@@ -47,6 +47,7 @@ from repro.apps.workload import (TenantProfile,  # noqa: E402
                                  make_drift_workload, make_drifted_suite)
 from repro.core.posterior import PosteriorConfig  # noqa: E402
 from repro.serving.simulator import ClusterSim, SimConfig  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 JSON_PATH = "BENCH_drift.json"
 
@@ -128,6 +129,7 @@ def _recovery_s(p, starts, acts, oracle_acts):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="short trace for CI (same scenario)")

@@ -47,6 +47,7 @@ from repro.core.admission import AdmissionConfig, DegradeConfig  # noqa: E402
 from repro.runtime.fault_tolerance import FaultEvent  # noqa: E402
 from repro.serving.backends import FaultConfig  # noqa: E402
 from repro.serving.simulator import ClusterSim, SimConfig  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 JSON_PATH = "BENCH_overload.json"
 
@@ -151,6 +152,7 @@ def _fault_canary(p, knowledge):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="short sweep for CI (same scenario, fewer points)")

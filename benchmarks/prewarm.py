@@ -34,6 +34,7 @@ sys.path.insert(0, "src")  # repo-root invocation without an installed package
 
 from benchmarks.common import Csv, kb, workload  # noqa: E402
 from repro.serving.simulator import ClusterSim, SimConfig  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 JSON_PATH = "BENCH_prewarm.json"
 
@@ -107,6 +108,7 @@ def run(csv: Csv, paper_scale: bool = False, seed: int = 7,
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI configuration (API drift canary)")

@@ -94,6 +94,7 @@ from benchmarks.common import Csv, kb  # noqa: E402
 from repro.apps.suite import T_IN, T_OUT  # noqa: E402
 from repro.core.refresh_config import RefreshConfig  # noqa: E402
 from repro.core.scheduler import HermesScheduler  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 MC_WALKERS = 128
 JSON_PATH = "BENCH_refresh_tick.json"
@@ -373,6 +374,7 @@ def run(csv: Csv, paper_scale: bool = False, seed: int = 7,
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI configuration (API drift canary)")

@@ -14,9 +14,11 @@ import time
 sys.path.insert(0, "src")
 
 from benchmarks.common import Csv  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--paper", action="store_true",
                     help="paper-scale workloads (slower)")

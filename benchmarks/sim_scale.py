@@ -55,6 +55,7 @@ from benchmarks.common import Csv, kb  # noqa: E402
 from repro.apps.suite import T_IN, T_OUT  # noqa: E402
 from repro.apps.workload import make_open_workload  # noqa: E402
 from repro.serving.simulator import ClusterSim, SimConfig  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 JSON_PATH = "BENCH_sim_scale.json"
 
@@ -241,6 +242,7 @@ def run(csv: Csv, smoke: bool = False, seed: int = 7):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI configuration (API drift canary)")

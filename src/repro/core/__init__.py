@@ -4,5 +4,3 @@ from repro.core.pdgraph import PDGraph, UnitNode, BackendSpec  # noqa: F401
 from repro.core.gittins import gittins_rank_hist, gittins_rank_samples  # noqa: F401
 from repro.core.arena import QueueState  # noqa: F401
 from repro.core.refresh_config import RefreshConfig  # noqa: F401
-from repro.core.refresh_pipeline import (refresh_ranks_delta,  # noqa: F401
-                                         refresh_ranks_fused)

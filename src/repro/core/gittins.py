@@ -108,8 +108,7 @@ def to_histogram_rows_jnp(total: jnp.ndarray, n_buckets: int = N_BUCKETS
     # to mul-by-reciprocal, so only the mul form has the same bits everywhere
     probs = onehot.sum(axis=1).astype(jnp.float32) * np.float32(
         1.0 / max(W, 1))
-    frac = jnp.arange(1, n_buckets + 1, dtype=jnp.float32) * np.float32(
-        1.0 / n_buckets)
+    frac = jnp.asarray(hist_fracs(n_buckets))
     # the max consumes the product so the following add cannot FMA-contract
     # it — contraction choices differ per compiled program and edge bits
     # must not depend on which program traced this twin.  Value-level
@@ -123,6 +122,58 @@ def to_histogram_rows_jnp(total: jnp.ndarray, n_buckets: int = N_BUCKETS
     return probs, edges
 
 
+def hist_fracs(n_buckets: int = N_BUCKETS) -> np.ndarray:
+    """Right-edge fractions ``(b + 1) / n`` as float32 reciprocal-multiplies
+    (div-by-constant is rewritten inconsistently across compilation
+    contexts).  Host constants, so every program — the Pallas kernel takes
+    them as scalar literals — multiplies the same folded values."""
+    return np.arange(1, n_buckets + 1, dtype=np.float32) \
+        * np.float32(1.0 / n_buckets)
+
+
+def _sum_last(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the small static last axis, left to right, keeping it as
+    size 1: one association order in every compiled program (XLA's and the
+    TPU kernel compiler's reduction trees differ), so kernel and oracle
+    sums agree to the bit."""
+    acc = x[..., 0:1]
+    for k in range(1, x.shape[-1]):
+        acc = acc + x[..., k:k + 1]
+    return acc
+
+
+def _rank_prep(probs, edges, attained_col):
+    """Per-bucket terms shared by both rank forms: bucket midpoints past
+    the (clamped) attained service, their conditional mass and remaining
+    service."""
+    left = jnp.concatenate(
+        [edges[:, :1] * 0 + (2 * edges[:, :1] - edges[:, 1:2]),
+         edges[:, :-1]], axis=1)
+    mids = 0.5 * (left + edges)                                  # (J, n)
+    max_edge = edges[:, -1:]
+    exhausted = attained_col >= max_edge                         # outlived dist
+    a = jnp.minimum(attained_col, max_edge * (1 - 1e-6))         # (J, 1)
+    alive = mids > a                                             # buckets past a
+    p_tail = jnp.where(alive, probs, 0.0)
+    tail_mass = jnp.maximum(_sum_last(p_tail), 1e-12)
+    p_cond = p_tail / tail_mass                                  # (J, n)
+    rem = jnp.where(alive, mids - a, 0.0)                        # (J, n)
+    return exhausted, alive, p_cond, rem
+
+
+def _candidate_terms(rem, delta, p_cond):
+    """E[min(X - a, Δ)] and P(X - a <= Δ) summands for candidate ``delta``;
+    the max consumes each product so no compiler can FMA-contract it into
+    the sum."""
+    return (jnp.maximum(jnp.minimum(rem, delta) * p_cond, 0.0),
+            jnp.where(rem <= delta, p_cond, 0.0))
+
+
+def _ratio(e_min, p_le, alive):
+    return jnp.where((p_le > 1e-12) & alive,
+                     e_min / jnp.maximum(p_le, 1e-12), _INF)
+
+
 def gittins_rank_core(probs: jnp.ndarray, edges: jnp.ndarray,
                       attained: jnp.ndarray) -> jnp.ndarray:
     """Vectorized Gittins ranks for a whole queue (pure jnp; traced both by
@@ -134,107 +185,40 @@ def gittins_rank_core(probs: jnp.ndarray, edges: jnp.ndarray,
     attained: (J,) service received so far
     returns (J,) ranks.
     """
-    left = jnp.concatenate([edges[:, :1] * 0 + (2 * edges[:, :1] - edges[:, 1:2]),
-                            edges[:, :-1]], axis=1)
-    mids = 0.5 * (left + edges)                                  # (J, n)
-    max_edge = edges[:, -1]
-    exhausted = attained >= max_edge                             # outlived dist
-    a = jnp.minimum(attained, max_edge * (1 - 1e-6))             # (J,)
-    alive = mids > a[:, None]                                     # buckets past a
-    p_tail = jnp.where(alive, probs, 0.0)
-    tail_mass = jnp.maximum(p_tail.sum(axis=1, keepdims=True), 1e-12)
-    p_cond = p_tail / tail_mass                                   # (J, n)
-    rem = jnp.where(alive, mids - a[:, None], 0.0)                # (J, n)
-
+    exhausted, alive, p_cond, rem = _rank_prep(probs, edges,
+                                               attained[:, None])
     # candidate Δ = rem at each alive bucket;  (J, n_delta, n_bucket)
-    delta = rem[:, :, None]                                       # Δ per candidate
-    rem_b = rem[:, None, :]
-    p_b = p_cond[:, None, :]
-    e_min = jnp.sum(jnp.minimum(rem_b, delta) * p_b, axis=-1)     # (J, n)
-    p_le = jnp.sum(jnp.where(rem_b <= delta, p_b, 0.0), axis=-1)  # (J, n)
-    ratio = jnp.where((p_le > 1e-12) & alive, e_min / jnp.maximum(p_le, 1e-12), _INF)
+    e_terms, p_terms = _candidate_terms(rem[:, None, :], rem[:, :, None],
+                                        p_cond[:, None, :])
+    ratio = _ratio(_sum_last(e_terms)[..., 0], _sum_last(p_terms)[..., 0],
+                   alive)
     ranks = jnp.min(ratio, axis=1)
     # a job that outlived every recorded sample carries no hazard information;
     # the conservative completion (decreasing-hazard / heavy-tail prior) is to
     # treat it as a long job: rank grows with attained instead of collapsing
     # into the last bucket (which would hand runaway jobs top priority)
-    return jnp.where(exhausted, attained, ranks)
-
-
-def hist_rows_loop(total: jnp.ndarray, n_buckets: int = N_BUCKETS
-                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``to_histogram_rows_jnp`` in 2-D-only form (kernel-traceable).
-
-    Bit-identical twin of :func:`to_histogram_rows_jnp` that replaces the
-    ``(A, W, n_buckets)`` one-hot intermediate with a static per-bucket
-    loop, so the Pallas fused-rank epilogue can trace it over a
-    ``(block_apps, W)`` VMEM tile (Mosaic has no 3-D one-hot).  Each
-    bucket's count is the same integer sum over the same walker axis, so
-    the float products cannot drift; ``tests/test_fused_rank.py`` pins the
-    twins bitwise."""
-    W = total.shape[1]
-    lo = total.min(axis=1, keepdims=True)                        # (A, 1)
-    hi = total.max(axis=1, keepdims=True)
-    hi = jnp.where(hi <= lo, lo + jnp.maximum(jnp.abs(lo) * 1e-3, 1e-6), hi)
-    norm = n_buckets / (hi - lo)
-    idx = ((total - lo) * norm).astype(jnp.int32)
-    idx = jnp.clip(idx, 0, n_buckets - 1)
-    cnt = jnp.concatenate(
-        [(idx == b).sum(axis=1, keepdims=True) for b in range(n_buckets)],
-        axis=1)
-    # reciprocal-multiply like the oracle (div-by-constant is rewritten
-    # inconsistently across compilation contexts); iota, not arange (arange
-    # would be a captured constant inside a Pallas kernel body) — iota + 1
-    # hits the same exact small-integer float32 values
-    probs = cnt.astype(jnp.float32) * np.float32(1.0 / max(W, 1))
-    frac = (jax.lax.broadcasted_iota(jnp.float32, (1, n_buckets), 1)
-            + 1.0) * np.float32(1.0 / n_buckets)
-    # max-guard mirrors to_histogram_rows_jnp: the max consumes the product
-    # so the add cannot FMA-contract it (value-level identity, see there)
-    span_frac = jnp.maximum((hi - lo) * frac, 0.0)
-    edges = lo + span_frac
-    last = jax.lax.broadcasted_iota(jnp.int32, edges.shape, 1) \
-        == n_buckets - 1
-    edges = jnp.where(last, hi, edges)
-    return probs, edges
+    return jnp.where(exhausted[:, 0], attained, ranks)
 
 
 def rank_rows_loop(probs: jnp.ndarray, edges: jnp.ndarray,
-                   attained_col: jnp.ndarray, n_buckets: int = N_BUCKETS
-                   ) -> jnp.ndarray:
-    """``gittins_rank_core`` in 2-D-only form (kernel-traceable).
+                   attained_col: jnp.ndarray) -> jnp.ndarray:
+    """:func:`gittins_rank_core` in 2-D-only form (kernel-traceable).
 
-    Bit-identical twin of :func:`gittins_rank_core` that unrolls the
-    candidate-Δ axis into a static loop: each candidate's
-    numerator/denominator is the same float32 sum over the same bucket
-    axis as one ``(J, n, n)`` slice of the core, and the final ``min`` is
-    order-independent, so the two can never diverge.  The Pallas
-    fused-rank epilogue traces this over a ``(block_apps, n_buckets)``
-    tile; ``tests/test_fused_rank.py`` pins the twins bitwise.
+    Bit-identical twin that unrolls the candidate-Δ axis into a static
+    loop: each candidate's numerator/denominator is the same float32
+    left-to-right sum (:func:`_sum_last`) of the same terms, and the final
+    ``min`` is order-independent, so the two can never diverge.  The Pallas
+    fused-rank epilogue traces this over a ``(rows, n_buckets)`` tile;
+    ``tests/test_fused_rank.py`` pins the twins bitwise.
 
     ``attained_col`` is ``(J, 1)`` (a column, not the core's ``(J,)`` —
     every intermediate stays 2-D); returns ``(J, 1)`` ranks."""
-    left = jnp.concatenate(
-        [edges[:, :1] * 0 + (2 * edges[:, :1] - edges[:, 1:2]),
-         edges[:, :-1]], axis=1)
-    mids = 0.5 * (left + edges)                                  # (J, n)
-    max_edge = edges[:, -1:]
-    exhausted = attained_col >= max_edge
-    a = jnp.minimum(attained_col, max_edge * (1 - 1e-6))         # (J, 1)
-    alive = mids > a
-    p_tail = jnp.where(alive, probs, 0.0)
-    tail_mass = jnp.maximum(p_tail.sum(axis=1, keepdims=True), 1e-12)
-    p_cond = p_tail / tail_mass
-    rem = jnp.where(alive, mids - a, 0.0)                        # (J, n)
+    exhausted, alive, p_cond, rem = _rank_prep(probs, edges, attained_col)
     ranks = None
-    for j in range(n_buckets):
-        delta = rem[:, j:j + 1]                                  # (J, 1)
-        e_min = jnp.sum(jnp.minimum(rem, delta) * p_cond,
-                        axis=1, keepdims=True)
-        p_le = jnp.sum(jnp.where(rem <= delta, p_cond, 0.0),
-                       axis=1, keepdims=True)
-        ratio = jnp.where((p_le > 1e-12) & alive[:, j:j + 1],
-                          e_min / jnp.maximum(p_le, 1e-12), _INF)
+    for j in range(edges.shape[1]):
+        e_terms, p_terms = _candidate_terms(rem, rem[:, j:j + 1], p_cond)
+        ratio = _ratio(_sum_last(e_terms), _sum_last(p_terms),
+                       alive[:, j:j + 1])
         ranks = ratio if ranks is None else jnp.minimum(ranks, ratio)
     return jnp.where(exhausted, attained_col, ranks)
 

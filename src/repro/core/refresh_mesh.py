@@ -49,7 +49,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.arena import QueueState
@@ -412,8 +411,8 @@ def _mesh_exec(mesh: Mesh, seed: int, n_walkers: int, max_steps: int,
                 rep, rep, rep, rep,                # base_key/uc/wt/K
                 rep, rep)                          # quant tables
     out_specs = (rows,) * 13
-    return jax.jit(shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _partition(slots: np.ndarray, n: int, pad: int

@@ -142,11 +142,10 @@ def moe_apply_ep(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
                                tiled=True)                        # (Tl, D)
         return y.reshape(B_l, S, D)
 
-    from jax.experimental.shard_map import shard_map
-    inner = shard_map(body, mesh=mesh,
-                      in_specs=(in_spec, router_spec, w_expert, w_expert,
-                                w_expert),
-                      out_specs=in_spec, check_rep=False)
+    inner = jax.shard_map(body, mesh=mesh,
+                          in_specs=(in_spec, router_spec, w_expert, w_expert,
+                                    w_expert),
+                          out_specs=in_spec, check_vma=False)
     y = inner(x, p["router"].astype(jnp.float32), p["wi"], p["wg"], p["wo"])
 
     if cfg.num_shared_experts:

@@ -5,14 +5,3 @@ Each kernel package ships:
   ops.py     jit'd public wrapper (auto interpret=True off-TPU)
   ref.py     pure-jnp oracle used by the allclose test sweeps
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``pltpu.CompilerParams``.
-
-    jax >= 0.5 renamed ``TPUCompilerParams`` to ``CompilerParams``; accept
-    whichever this jaxlib ships so the kernels import on both."""
-    cls = getattr(_pltpu, "CompilerParams", None) \
-        or getattr(_pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

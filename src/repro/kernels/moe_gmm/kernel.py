@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_scr, *, n_d: int):
@@ -62,7 +61,7 @@ def moe_gmm_kernel(x: jnp.ndarray, w: jnp.ndarray, *, block_c: int = 128,
                                lambda e, c, n, d: (e, c, n)),
         out_shape=jax.ShapeDtypeStruct((E, C, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

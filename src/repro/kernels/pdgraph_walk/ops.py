@@ -55,12 +55,16 @@ from repro.kernels.pdgraph_walk.ref import walk_phase_ref, walker_streams  # noq
 # either run the kernel or warn) — note jit caching means it reflects the
 # last TRACE, so assert right after a fresh-shape call.
 LAST_DISPATCH: Optional[str] = None
+# ... and whether a kernel dispatch ran through the Pallas interpreter
+LAST_INTERPRET: Optional[bool] = None
 _FALLBACK_WARNED: set = set()
 
 
-def _note_dispatch(requested: Optional[str], actual: str, reason: str = ""):
-    global LAST_DISPATCH
+def _note_dispatch(requested: Optional[str], actual: str, reason: str = "",
+                   interpret: Optional[bool] = None):
+    global LAST_DISPATCH, LAST_INTERPRET
     LAST_DISPATCH = actual
+    LAST_INTERPRET = interpret if actual == "pallas" else None
     if requested == "pallas" and actual != "pallas" \
             and reason not in _FALLBACK_WARNED:
         _FALLBACK_WARNED.add(reason)
@@ -99,35 +103,24 @@ def _phase(flat_tables, ov_tables, state, *, step0, n_steps, lanes_per_app,
     """One walk phase via the kernel or its jnp twin (identical bits).
 
     ``arrivals`` (N, U) switches on first-arrival tracking; both backends
-    carry it (the kernel as a (U, N) lane-major block), bit-identically.
-    ``po_tables`` (flat posterior CDF/scale) reach both backends: the twin
-    gathers them, the kernel consumes them as app-blocked one-hot operands
-    (step0 == 0 phases only — the dispatcher disables compaction for
-    posterior kernel walks).  ``quant_tables`` (qsv, icdf) switch the twin
-    to the lossless 16-bit quantized step (``quant.walk_phase_quant``,
-    bit-identical; ineligible with overrides — the caller gates)."""
+    carry it (the kernel as a (U, L) lane-major block), bit-identically.
+    ``ov_tables`` / ``po_tables`` (flat per-app override and posterior
+    rows) reach both backends: the twin gathers them, the kernel consumes
+    them as app-blocked one-hot operands (app-major phases only — the
+    dispatcher disables compaction for kernel walks with per-app tables).
+    ``quant_tables`` (qsv, icdf) switch the twin to the lossless 16-bit
+    quantized step (``quant.walk_phase_quant``, bit-identical; ineligible
+    with overrides — the caller gates)."""
     fsamples, fcounts, fcum = flat_tables
     fov_s, fov_c = ov_tables
     fpo_cum, fpo_scale = po_tables
     cur, total, done, gi, app, stream, lane, executed = state
     if impl == "pallas":
-        ex = executed if executed is not None \
-            else jnp.zeros_like(total)
-        ovs_t = fov_s.T if fov_s is not None \
-            else jnp.zeros((1, 1), jnp.float32)
-        ovc = fov_c if fov_c is not None else jnp.zeros((1,), jnp.float32)
-        out = pdgraph_walk_kernel(
-            fsamples.T, fcounts, fcum.T, ovs_t, ovc,
-            cur, gi, app, stream, lane, ex, total, done,
-            arrivals.T if arrivals is not None else None,
-            fpo_scale, fpo_cum.T if fpo_cum is not None else None,
+        return pdgraph_walk_kernel(
+            fsamples.T, fcounts, fcum.T, cur, gi, stream, lane, executed,
+            total, done, arrivals, fov_s, fov_c, fpo_scale, fpo_cum,
             step0=step0, n_steps=n_steps, lanes_per_app=lanes_per_app,
-            with_overrides=fov_s is not None,
-            with_executed=executed is not None,
             interpret=interpret)
-        if arrivals is not None:
-            return out[0], out[1], out[2], out[3].T
-        return out
     if quant_tables is not None and fov_s is None:
         qsv, qic = quant_tables
         return walk_phase_quant(qsv, qic, cur, total, done, gi, app,
@@ -187,18 +180,18 @@ def pdgraph_walk(samples: jnp.ndarray,        # (G, U, S)
     ``track_arrivals`` additionally returns per-walker first-arrival times
     into every unit — ``((A, W), (A, W, U), spill)`` — feeding the fused
     prewarm planner.  Both backends carry the arrival state (the kernel as a
-    (U, N) lane-major block), so the TPU path keeps kernel speed with
+    (U, L) lane-major block), so the TPU path keeps kernel speed with
     prewarm tracking on; the counter-RNG draws don't depend on the extra
     carry, so totals are bit-identical either way.
 
     ``po_cum (A, U, U+1)`` / ``po_scale (A, U)`` switch on posterior
     sampling (online PDGraph learning, ``repro.core.posterior``).  Both
-    backends consume them bit-identically: the twin as flat gathers, the
-    kernel as app-blocked one-hot operands.  Blocked per-app tables
-    require app-aligned lane blocks, which only hold before compaction —
-    posterior kernel walks therefore run single-phase (compaction is
-    exact, so the bits cannot differ; only the spill count, pinned at 0,
-    and the step cost on absorbed lanes do).
+    backends consume them — and the override tables — bit-identically: the
+    twin as flat gathers, the kernel as app-blocked one-hot operands.
+    Blocked per-app tables require app-aligned lane rows, which only hold
+    before compaction — kernel walks with either therefore run
+    single-phase (compaction is exact, so the bits cannot differ; only the
+    spill count, pinned at 0, and the step cost on absorbed lanes do).
 
     ``quant`` — precomputed ``(qsv, icdf)`` lossless 16-bit step tables
     (``quant.quant_tables``) for the jnp twin; ignored on the kernel path
@@ -208,11 +201,11 @@ def pdgraph_walk(samples: jnp.ndarray,        # (G, U, S)
     requested = impl
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    _note_dispatch(requested, impl)
-    if po_cum is not None and impl == "pallas":
-        compact_schedule = ()     # app-blocked tables need phase-1 lanes
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    _note_dispatch(requested, impl, interpret=interpret)
+    if impl == "pallas" and (po_cum is not None or ov_samples is not None):
+        compact_schedule = ()     # app-blocked tables need phase-1 lanes
     A = graph_idx.shape[0]
     G, U, S = samples.shape
     N = A * n_walkers
@@ -389,34 +382,25 @@ def pdgraph_walk_ranked(samples: jnp.ndarray,     # (G, U, S)
     attained = jnp.asarray(attained, jnp.float32)
 
     if impl == "pallas":
-        _note_dispatch(requested, "pallas")
-        flat = (samples.reshape(G * U, S),
-                counts.reshape(G * U).astype(jnp.float32),
-                cum_trans.reshape(G * U, U + 1))
+        _note_dispatch(requested, "pallas", interpret=interpret)
         with_ov = ov_samples is not None
-        ovs_t = ov_samples.reshape(A * U, -1).T if with_ov \
-            else jnp.zeros((1, 1), jnp.float32)
-        ovc = ov_counts.reshape(A * U).astype(jnp.float32) if with_ov \
-            else jnp.zeros((1,), jnp.float32)
-        po_s = po_scale.reshape(A * U).astype(jnp.float32) \
-            if po_cum is not None else None
-        po_c_t = po_cum.reshape(A * U, U + 1).T \
-            if po_cum is not None else None
         rep = lambda a, dt: jnp.repeat(jnp.asarray(a, dt), W)  # noqa: E731
         done0 = (jnp.zeros((N,), bool) if valid is None
                  else jnp.repeat(~jnp.asarray(valid, bool), W))
-        arr_t = (jnp.full((U, N), ARRIVAL_NEVER, jnp.float32)
-                 if track_arrivals else None)
         total_o, probs, edges, ranks, arrstats = pdgraph_walk_fused_kernel(
-            flat[0].T, flat[1], flat[2].T, ovs_t, ovc, attained,
+            samples.reshape(G * U, S).T,
+            counts.reshape(G * U).astype(jnp.float32),
+            cum_trans.reshape(G * U, U + 1).T, attained,
             rep(start, jnp.int32), rep(graph_idx, jnp.int32),
-            jnp.repeat(jnp.arange(A, dtype=jnp.int32), W),
             rep(streams, jnp.uint32),
             jnp.tile(jnp.arange(W, dtype=jnp.uint32), A),
-            rep(executed, jnp.float32),
-            jnp.zeros((N,), jnp.float32), done0, arr_t, po_s, po_c_t,
+            rep(executed, jnp.float32), done0,
+            ov_samples.reshape(A * U, -1) if with_ov else None,
+            ov_counts.reshape(A * U) if with_ov else None,
+            po_scale.reshape(A * U) if po_cum is not None else None,
+            po_cum.reshape(A * U, U + 1) if po_cum is not None else None,
             n_steps=max_steps, lanes_per_app=W, n_buckets=n_buckets,
-            arrival_never=ARRIVAL_NEVER, with_overrides=with_ov,
+            arrival_never=ARRIVAL_NEVER, with_arrivals=track_arrivals,
             with_rank=with_rank, with_total=with_total,
             interpret=interpret)
         out = {"probs": probs, "edges": edges, "ranks": ranks,
@@ -425,7 +409,7 @@ def pdgraph_walk_ranked(samples: jnp.ndarray,     # (G, U, S)
             rem = total_o.reshape(A, W)
             out["total"] = attained[:, None] + jnp.maximum(rem, 0.0)
         if track_arrivals:
-            st = arrstats.reshape(A, U, n_buckets + 3)
+            st = arrstats                          # (A, U, nb + 3)
             out.update(a_hist=st[..., :n_buckets], a_lo=st[..., n_buckets],
                        a_span=st[..., n_buckets + 1],
                        a_reach=st[..., n_buckets + 2])

@@ -53,8 +53,11 @@ def counter_uniforms(stream: jnp.ndarray, ctr: jnp.ndarray
     """Two [0,1) float32 uniforms (16-bit resolution) from one hash of a
     per-walker stream id and a per-step counter."""
     bits = fmix32(stream + ctr * GOLDEN)
-    r = (bits >> 16).astype(jnp.float32) * _U16_SCALE
-    r2 = (bits & np.uint32(0xFFFF)).astype(jnp.float32) * _U16_SCALE
+    # via int32 (same values, both < 2**16): the TPU kernel compiler has
+    # no uint32 -> float32 conversion
+    r = (bits >> 16).astype(jnp.int32).astype(jnp.float32) * _U16_SCALE
+    r2 = (bits & np.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32) \
+        * _U16_SCALE
     return r, r2
 
 

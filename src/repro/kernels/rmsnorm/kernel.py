@@ -10,7 +10,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _kernel(x_ref, scale_ref, o_ref, *, eps: float):
@@ -34,7 +33,7 @@ def rmsnorm_kernel(x: jnp.ndarray, scale: jnp.ndarray, *, eps: float = 1e-5,
                   pl.BlockSpec((D,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, D), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, scale)

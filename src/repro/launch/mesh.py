@@ -22,10 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} "
             "(dryrun.py sets XLA_FLAGS=--xla_force_host_platform_device_count=512)")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older jax.make_mesh signature without devices kwarg
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:

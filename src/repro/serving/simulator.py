@@ -379,8 +379,8 @@ class ClusterSim:
         traces that would otherwise run for hours on the baseline engine);
         ``progress`` is an optional callable invoked with the sim after
         every drained micro-batch (scale benchmarks sample wall clock vs
-        queue size through it).  Both default to off and leave the hot loop
-        untouched."""
+        queue size through it); a true return value ends the run there.
+        Both default to off and leave the hot loop untouched."""
         for inst in instances:
             self._push(inst.arrival, "arrival", inst)
         self._push(self.cfg.bucket_s, "tick", None)
@@ -448,8 +448,8 @@ class ClusterSim:
                     self._spawn_unit(sim)
             self._reschedule()
             self.events_processed += n
-            if progress is not None:
-                progress(self)
+            if progress is not None and progress(self):
+                break
 
         self.let.finalize(self.now)
         stall_stats = {
