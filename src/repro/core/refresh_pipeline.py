@@ -59,6 +59,7 @@ from repro.kernels.pdgraph_walk.ops import (pdgraph_walk,
                                             pdgraph_walk_ranked,
                                             walker_streams)
 from repro.kernels.pdgraph_walk.quant import quant_tables
+from repro.runtime.tracing import span
 
 
 def _arrival_hists(arr, n_buckets):
@@ -500,14 +501,17 @@ class FusedRefresh:
     mean: Optional[np.ndarray]
 
 
-def _prewarm_args(packed, prewarm_table):
+def _prewarm_args(packed, prewarm_table, io=None):
+    """The ``PrewarmTable`` constants, uploaded through ``io`` (a
+    :class:`_Crossings`; a fresh one counts for no one)."""
+    io = io or _Crossings()
     if prewarm_table is not None:
-        return (jnp.asarray(prewarm_table.unit_class),
-                jnp.asarray(prewarm_table.warmup))
+        return (io.put(prewarm_table.unit_class),
+                io.put(prewarm_table.warmup))
     # 1-class placeholders keep the arg list static-shape friendly
-    return (jnp.full((packed.samples.shape[0], packed.n_units, 1), -1,
-                     jnp.int32),
-            jnp.zeros((1,), jnp.float32))
+    return (io.make(jnp.full, (packed.samples.shape[0], packed.n_units, 1),
+                    -1, jnp.int32),
+            io.make(jnp.zeros, (1,), jnp.float32))
 
 
 def _ranked_args(packed: PackedKB, walker: str, impl: Optional[str],
@@ -541,15 +545,16 @@ def _quant_dummies():
 
 
 def _dispatch_rows(qs: QueueState, slots: np.ndarray, packed: PackedKB,
-                   prewarm_table, pad_to: Optional[int] = None):
+                   prewarm_table, pad_to: Optional[int] = None, io=None):
     """Shared host-side marshalling for the refresh entry points: padded
-    row gather, override-width trim, prewarm constants."""
+    row gather, override-width trim, prewarm constants (uploaded through
+    ``io``)."""
     gi, start, executed, attained, kid, rid, stretch, ovs, ovc = \
         qs.gather(slots, pad_to=pad_to)
     with_ov = qs.override_apps > 0
     if not with_ov and ovs.shape[2] > 1:
         ovs = ovs[:, :, :1]                  # keep the no-override jit cache
-    uc, wt = _prewarm_args(packed, prewarm_table)
+    uc, wt = _prewarm_args(packed, prewarm_table, io)
     return gi, start, executed, attained, kid, rid, stretch, ovs, ovc, \
         with_ov, uc, wt
 
@@ -637,21 +642,45 @@ def refresh_ranks_fused(packed: PackedKB, qs: QueueState, base_key, seed,
 @dataclass
 class DeltaTick:
     """Results of one delta tick: arena-wide ranks plus the set of slots
-    whose estimates were actually re-walked."""
+    whose estimates were actually re-walked, and the dispatch's
+    host->device uploads and device->host reads."""
     ranks: np.ndarray          # (capacity,) — index by slot id; holes garbage
     spill: int
     walked: np.ndarray         # slot ids re-walked (and scattered) this tick
+    h2d: int = 0
+    d2h: int = 0
 
 
-def _retrigger_rows(qs: QueueState, walked: np.ndarray):
+class _Crossings:
+    """Counts one dispatch's host<->device transfers: every upload goes
+    through ``put`` (a host array) or ``make`` (a one-scalar constant built
+    on the device, such as ``jnp.zeros``, whose fill value crosses), every
+    read-back through ``get``."""
+
+    def __init__(self):
+        self.h2d = self.d2h = 0
+
+    def put(self, x):
+        self.h2d += 1
+        return jnp.asarray(x)
+
+    def make(self, fn, *args):
+        self.h2d += 1
+        return fn(*args)
+
+    def get(self, x):
+        self.d2h += 1
+        return np.asarray(x)
+
+
+def _retrigger_rows(qs: QueueState, walked: np.ndarray, io: _Crossings):
     """Arena-wide rows for the trigger re-conditioning: graph ids, elapsed
     service since each slot's last walk (0 for the rows walked THIS tick),
     and the stretch EWMA."""
     delta_all = qs.attained - qs.a_att
     if len(walked):
         delta_all[walked] = 0.0
-    return (jnp.asarray(qs.graph_idx), jnp.asarray(delta_all),
-            jnp.asarray(qs.stretch))
+    return io.put(qs.graph_idx), io.put(delta_all), io.put(qs.stretch)
 
 
 def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
@@ -681,86 +710,117 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
     apps that were never re-walked; ``retrigger=False`` (event-path subset
     calls) computes walk-time triggers for just the walked rows, keeping
     per-event cost sized by the event.  Does NOT bump refresh ids; callers
-    bump ``walked`` after consuming."""
+    bump ``walked`` after consuming.
+
+    The dispatch runs in three host spans, ``hermes.<path>.prepare`` (row
+    gather and uploads, up to the enqueue), ``.wait`` (every read of the
+    results) and ``.consume`` (the host mirrors), with ``<path>`` ``tick``
+    for ``retrigger`` and ``event`` otherwise; the returned tick counts
+    the uploads (``h2d``) and reads (``d2h``)."""
     if qs.n_shards != 1:
         raise ValueError("refresh_ranks_delta serves 1-shard arenas; "
                          "mesh-sharded stores go through refresh_ranks_mesh")
+    path = "tick" if retrigger else "event"
+    io = _Crossings()
     with_pw = prewarm_table is not None
-    qs.ensure_result_rows(n_buckets,
-                          prewarm_table.n_classes if with_pw else None,
-                          arrivals=with_pw)
-    att_all = jnp.asarray(qs.attained)
     D = len(walked)
-    if D == 0:
-        if with_pw and retrigger:
-            uc, wt = _prewarm_args(packed, prewarm_table)
-            gi_all, delta_all, stretch_all = _retrigger_rows(qs, walked)
-            ranks, trigger, reach = _rank_retrigger_pipeline(
-                qs.d_probs, qs.d_edges, att_all,
-                qs.a_hist, qs.a_lo, qs.a_span, qs.a_reach,
-                gi_all, delta_all, stretch_all,
-                uc, wt, jnp.float32(prewarm_k), n_walkers=n_walkers)
-            qs.trig = np.array(trigger)         # writable host mirrors
-            qs.reach = np.array(reach)
+    with span(path + ".prepare"):
+        qs.ensure_result_rows(n_buckets,
+                              prewarm_table.n_classes if with_pw else None,
+                              arrivals=with_pw)
+        att_all = io.put(qs.attained)
+        if D == 0:
+            if with_pw and retrigger:
+                uc, wt = _prewarm_args(packed, prewarm_table, io)
+                gi_all, delta_all, stretch_all = \
+                    _retrigger_rows(qs, walked, io)
+                ranks, trigger, reach = _rank_retrigger_pipeline(
+                    qs.d_probs, qs.d_edges, att_all,
+                    qs.a_hist, qs.a_lo, qs.a_span, qs.a_reach,
+                    gi_all, delta_all, stretch_all,
+                    uc, wt, io.make(jnp.float32, prewarm_k),
+                    n_walkers=n_walkers)
+                out = {"ranks": ranks, "trigger": trigger, "reach": reach}
+            else:
+                out = {"ranks": gittins_rank_hist(qs.d_probs, qs.d_edges,
+                                                  att_all)}
         else:
-            ranks = gittins_rank_hist(qs.d_probs, qs.d_edges, att_all)
-        return DeltaTick(np.asarray(ranks), 0, walked)
-    gi, start, executed, attained, kid, rid, stretch, ovs, ovc, with_ov, \
-        uc, wt = _dispatch_rows(qs, walked, packed, prewarm_table)
-    ap = len(gi)
-    # padding rows scatter out of bounds -> dropped (never clobber a slot)
-    slot_idx = np.concatenate([np.asarray(walked, np.int64),
-                               np.full(ap - D, qs.capacity, np.int64)])
-    if with_pw and retrigger:
-        gi_all, delta_all, stretch_all = _retrigger_rows(qs, walked)
-    else:
-        z = jnp.zeros((1,), jnp.float32)
-        gi_all, delta_all, stretch_all = jnp.zeros((1,), jnp.int32), z, z
-    dummy = jnp.zeros((1, 1), jnp.float32)
-    with_po = posterior is not None
-    if with_po:
-        qs.ensure_posterior_rows()
-    post = qs.post if with_po else jnp.zeros((1, 1, 1), jnp.float32)
-    rank_in_kernel, qsv, qic = _ranked_args(packed, walker, impl,
-                                            rank_in_kernel)
-    (qs.d_probs, qs.d_edges, ranks, spill, sup, opt, mean,
-     a_hist, a_lo, a_span, a_reach, trigger, reach) = _delta_pipeline(
-        packed.samples, packed.counts, packed.cum_trans,
-        jnp.asarray(gi), jnp.asarray(start), jnp.asarray(executed),
-        jnp.asarray(attained), jnp.asarray(kid), jnp.asarray(rid),
-        base_key, np.uint32(int(seed) & 0xFFFFFFFF),
-        jnp.asarray(ovs), jnp.asarray(ovc),
-        jnp.asarray(np.arange(ap) < D), jnp.asarray(stretch),
-        jnp.asarray(slot_idx), qs.d_probs, qs.d_edges, att_all,
-        qs.a_hist if with_pw else dummy,
-        qs.a_lo if with_pw else dummy,
-        qs.a_span if with_pw else dummy,
-        qs.a_reach if with_pw else dummy,
-        gi_all, delta_all, stretch_all,
-        uc, wt, jnp.float32(prewarm_k), post, qsv, qic,
-        n_walkers=n_walkers, max_steps=max_steps, n_buckets=n_buckets,
-        walker=walker, impl=impl, with_overrides=with_ov,
-        compact_after=compact_after, compact_shrink=compact_shrink,
-        with_prewarm=with_pw, with_retrigger=retrigger,
-        with_triage=with_triage, with_posterior=with_po,
-        branch_strength=(posterior.branch_strength if with_po else 8.0),
-        demand_strength=(posterior.demand_strength if with_po else 8.0),
-        rank_in_kernel=rank_in_kernel)
-    if with_pw:
-        qs.a_hist, qs.a_lo, qs.a_span, qs.a_reach = \
-            a_hist, a_lo, a_span, a_reach
-        qs.a_att[walked] = qs.attained[walked]
-    _store_results(qs, walked, n_buckets,
-                   prewarm_table.n_classes if with_pw else None,
-                   np.asarray(sup)[:D] if with_triage else None,
-                   np.asarray(opt)[:D] if with_triage else None,
-                   np.asarray(mean)[:D] if with_triage else None,
-                   None, None)
-    if with_pw:
-        if retrigger:
-            qs.trig = np.array(trigger)         # whole-arena mirrors
-            qs.reach = np.array(reach)
-        else:
-            qs.trig[walked] = np.asarray(trigger)[:D]
-            qs.reach[walked] = np.asarray(reach)[:D]
-    return DeltaTick(np.asarray(ranks), int(spill), walked)
+            gi, start, executed, attained, kid, rid, stretch, ovs, ovc, \
+                with_ov, uc, wt = _dispatch_rows(qs, walked, packed,
+                                                 prewarm_table, io=io)
+            ap = len(gi)
+            # padding rows scatter out of bounds -> dropped (never clobber
+            # a slot)
+            slot_idx = np.concatenate([np.asarray(walked, np.int64),
+                                       np.full(ap - D, qs.capacity,
+                                               np.int64)])
+            if with_pw and retrigger:
+                gi_all, delta_all, stretch_all = \
+                    _retrigger_rows(qs, walked, io)
+            else:
+                z = io.make(jnp.zeros, (1,), jnp.float32)
+                gi_all, delta_all, stretch_all = \
+                    io.make(jnp.zeros, (1,), jnp.int32), z, z
+            dummy = io.make(jnp.zeros, (1, 1), jnp.float32)
+            with_po = posterior is not None
+            if with_po:
+                qs.ensure_posterior_rows()
+            post = qs.post if with_po else \
+                io.make(jnp.zeros, (1, 1, 1), jnp.float32)
+            rank_in_kernel, qsv, qic = _ranked_args(packed, walker, impl,
+                                                    rank_in_kernel)
+            (qs.d_probs, qs.d_edges, ranks, spill, sup, opt, mean,
+             a_hist, a_lo, a_span, a_reach, trigger, reach) = \
+                _delta_pipeline(
+                    packed.samples, packed.counts, packed.cum_trans,
+                    io.put(gi), io.put(start), io.put(executed),
+                    io.put(attained), io.put(kid), io.put(rid),
+                    base_key,
+                    io.put(np.asarray(int(seed) & 0xFFFFFFFF, np.uint32)),
+                    io.put(ovs), io.put(ovc),
+                    io.put(np.arange(ap) < D), io.put(stretch),
+                    io.put(slot_idx), qs.d_probs, qs.d_edges, att_all,
+                    qs.a_hist if with_pw else dummy,
+                    qs.a_lo if with_pw else dummy,
+                    qs.a_span if with_pw else dummy,
+                    qs.a_reach if with_pw else dummy,
+                    gi_all, delta_all, stretch_all,
+                    uc, wt, io.make(jnp.float32, prewarm_k), post, qsv, qic,
+                    n_walkers=n_walkers, max_steps=max_steps,
+                    n_buckets=n_buckets, walker=walker, impl=impl,
+                    with_overrides=with_ov, compact_after=compact_after,
+                    compact_shrink=compact_shrink, with_prewarm=with_pw,
+                    with_retrigger=retrigger, with_triage=with_triage,
+                    with_posterior=with_po,
+                    branch_strength=(posterior.branch_strength if with_po
+                                     else 8.0),
+                    demand_strength=(posterior.demand_strength if with_po
+                                     else 8.0),
+                    rank_in_kernel=rank_in_kernel)
+            out = {"ranks": ranks, "spill": spill}
+            if with_triage:
+                out.update(sup=sup, opt=opt, mean=mean)
+            if with_pw:
+                qs.a_hist, qs.a_lo, qs.a_span, qs.a_reach = \
+                    a_hist, a_lo, a_span, a_reach
+                out.update(trigger=trigger, reach=reach)
+    with span(path + ".wait"):
+        got = {k: io.get(v) for k, v in out.items()}
+    with span(path + ".consume"):
+        if D and with_pw:
+            qs.a_att[walked] = qs.attained[walked]
+        if D:
+            _store_results(qs, walked, n_buckets,
+                           prewarm_table.n_classes if with_pw else None,
+                           *(got[k][:D] if with_triage else None
+                             for k in ("sup", "opt", "mean")),
+                           None, None)
+        if "trigger" in got:
+            if retrigger:
+                qs.trig = np.array(got["trigger"])   # writable, whole arena
+                qs.reach = np.array(got["reach"])
+            else:
+                qs.trig[walked] = got["trigger"][:D]
+                qs.reach[walked] = got["reach"][:D]
+        return DeltaTick(got["ranks"], int(got["spill"]) if D else 0,
+                         walked, io.h2d, io.d2h)

@@ -35,6 +35,7 @@ from repro.core.refresh_config import (_UNSET, RefreshConfig,
 from repro.core.refresh_mesh import RefreshMesh, refresh_ranks_mesh
 from repro.core.refresh_pipeline import (refresh_ranks_delta,
                                          refresh_ranks_fused)
+from repro.runtime.tracing import span
 
 
 @dataclass
@@ -134,6 +135,9 @@ class HermesScheduler:
         self._packed = None               # (kb versions, PackedKB) cache
         self._qstate = None               # fused-mode queue buffers (lazy)
         self.fused_spill = 0              # walkers truncated by compaction
+        # single-arena delta dispatches and their host<->device transfers
+        self.refresh_stats = {"event_dispatches": 0, "tick_dispatches": 0,
+                              "h2d": 0, "d2h": 0}
         self.warmup_table = warmup_table  # per-key warm-up cost overrides
         self._prewarm_tab = None          # (kb token, PrewarmTable) cache
         self.prewarm_plan: Optional[PrewarmPlan] = None   # last fused plan
@@ -358,12 +362,61 @@ class HermesScheduler:
         Event-path subset calls (``app_ids`` given) walk only the dirty
         slots the event actually touched; other dirty slots keep their mark
         and walk on the next full tick, so per-event cost stays sized by
-        the event, not by unrelated queue churn."""
+        the event, not by unrelated queue churn.
+
+        On the single arena the host work before and after
+        :func:`refresh_ranks_delta` runs in its ``hermes.<path>.prepare``
+        and ``.consume`` spans, and the dispatch is counted in
+        ``refresh_stats``."""
         qs = self._ensure_qstate()
         if len(qs) == 0:
             return {}
         full = app_ids is None
-        if full:
+        if self.refresh_mesh is not None:
+            live, walked, tab = self._take_walk(qs, app_ids)
+            return self._priorities_mesh(qs, live, walked, now, tab, full)
+        path = "tick" if full else "event"
+        with span(path + ".prepare"):
+            live, walked, tab = self._take_walk(qs, app_ids)
+        tick = refresh_ranks_delta(
+            self._packed[1], qs, self._base_key, self._seed,
+            walked=walked, n_walkers=self.mc_walkers,
+            n_buckets=self.n_buckets, walker=self.walker,
+            compact_after=self.compact_after,
+            compact_shrink=self.compact_shrink,
+            prewarm_table=tab, prewarm_k=self.K, retrigger=full,
+            with_triage=self._with_triage, posterior=self.posterior,
+            rank_in_kernel=self.rank_in_kernel)
+        with span(path + ".consume"):
+            self.fused_spill += tick.spill
+            stats = self.refresh_stats
+            stats[path + "_dispatches"] += 1
+            stats["h2d"] += tick.h2d
+            stats["d2h"] += tick.d2h
+            if full:
+                qs.take_rank_dirty()     # arena-wide re-rank covered everyone
+            if tab is not None:
+                # full ticks re-conditioned EVERY slot's trigger rows on the
+                # service attained since its walk, so the plan covers the
+                # whole queue; event-path refreshes only re-planned the
+                # walked rows
+                plan_slots = qs.occupied() if full else walked
+                if len(plan_slots):
+                    self._stash_plan(PrewarmPlan.from_store(qs, plan_slots,
+                                                            now, tab))
+            if len(walked):
+                qs.bump_refresh(walked)
+                for s in walked:
+                    self.apps[qs.ids[int(s)]].refreshes += 1
+            return self._ranks_from_store(qs, live, tick.ranks, now)
+
+    def _take_walk(self, qs, app_ids: Optional[List[str]]):
+        """What a delta tick walks: ``(live apps it ranks, slots to walk,
+        prewarm table | None)``.  A full tick (``app_ids`` None) repacks
+        and drains the whole dirty set; an event drains only the touched
+        slots' marks.  Pending posterior observations are flushed into the
+        slots about to walk."""
+        if app_ids is None:
             # repack epoch boundary: no slot id is held outside the store
             # between full ticks, so a shrink (mirrors remapped in place,
             # dispatch shapes retrace at the new capacity) is safe here
@@ -383,33 +436,7 @@ class HermesScheduler:
         if self.posterior is not None:
             self._posterior_flush(qs, walked)
         tab = self._prewarm_table() if self.prewarm_batched else None
-        if self.refresh_mesh is not None:
-            return self._priorities_mesh(qs, live, walked, now, tab, full)
-        tick = refresh_ranks_delta(
-            self._packed[1], qs, self._base_key, self._seed,
-            walked=walked, n_walkers=self.mc_walkers,
-            n_buckets=self.n_buckets, walker=self.walker,
-            compact_after=self.compact_after,
-            compact_shrink=self.compact_shrink,
-            prewarm_table=tab, prewarm_k=self.K, retrigger=full,
-            with_triage=self._with_triage, posterior=self.posterior,
-            rank_in_kernel=self.rank_in_kernel)
-        self.fused_spill += tick.spill
-        if full:
-            qs.take_rank_dirty()     # arena-wide re-rank covered everyone
-        if tab is not None:
-            # full ticks re-conditioned EVERY slot's trigger rows on the
-            # service attained since its walk, so the plan covers the whole
-            # queue; event-path refreshes only re-planned the walked rows
-            plan_slots = qs.occupied() if full else walked
-            if len(plan_slots):
-                self._stash_plan(PrewarmPlan.from_store(qs, plan_slots,
-                                                        now, tab))
-        if len(walked):
-            qs.bump_refresh(walked)
-            for s in walked:
-                self.apps[qs.ids[int(s)]].refreshes += 1
-        return self._ranks_from_store(qs, live, tick.ranks, now)
+        return live, walked, tab
 
     def _priorities_mesh(self, qs, live: List[AppRuntime],
                          walked: np.ndarray, now: float, tab,

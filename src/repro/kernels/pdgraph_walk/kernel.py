@@ -257,10 +257,11 @@ def _rows_per_block(rows: int) -> int:
     return 8 if rows % 8 == 0 else rows
 
 
-def _call(kernel, operands, out_shape, rb, rows, interpret):
+def _call(kernel, operands, out_shape, rb, rows, interpret, name):
     """``operands``: ``(array, whole)`` — the whole array every step, or
     blocks of ``1 / nblk`` of its leading axis (``rb`` rows of walker
-    state, ``rb * U`` rows of per-app tables)."""
+    state, ``rb * U`` rows of per-app tables).  ``name`` names the kernel's
+    custom call in the compiled program, and so on the device trace."""
     nblk = rows // rb
 
     def spec(shape, whole):
@@ -279,6 +280,7 @@ def _call(kernel, operands, out_shape, rb, rows, interpret):
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name=name,
     )(*[t for t, _ in operands]))
 
 
@@ -344,7 +346,8 @@ def pdgraph_walk_kernel(samples_t, counts_row, cum_t,
         with_executed=executed is not None,
         with_arrivals=arrivals is not None,
         with_posterior=po_cum is not None)
-    out = _call(kernel, operands, out_shape, rb, R, interpret)
+    out = _call(kernel, operands, out_shape, rb, R, interpret,
+                "pdgraph_walk")
     res = (out[0].reshape(N), out[1].reshape(N), out[2].reshape(N) != 0)
     if arrivals is not None:
         res += (out[3].transpose(0, 2, 1).reshape(N, U),)
@@ -403,7 +406,8 @@ def pdgraph_walk_fused_kernel(samples_t, counts_row, cum_t, attained,
         with_arrivals=with_arrivals, with_posterior=po_cum is not None,
         n_buckets=nb, with_rank=with_rank, with_total_out=with_total,
         arrival_never=arrival_never)
-    out = _call(kernel, operands, out_shape, rb, A, interpret)
+    out = _call(kernel, operands, out_shape, rb, A, interpret,
+                "pdgraph_walk_ranked")
     total_o = out.pop(0).reshape(N) if with_total else None
     probs_o = edges_o = ranks_o = None
     if with_rank:

@@ -1,1 +1,1 @@
-"""Runtime services: fault tolerance."""
+"""Runtime services: fault tolerance, host spans for the profiler."""

@@ -54,6 +54,7 @@ from repro.core.scheduler import HermesScheduler
 from repro.runtime.fault_tolerance import (BackendStragglerWatchdog,
                                            FailureInjector, HeartbeatRegistry,
                                            requeue_backoff)
+from repro.runtime.tracing import span
 from repro.serving.backends import Backend, FaultConfig, build_pools
 from repro.serving.events import ENGINES, make_event_queue, make_wait_queue
 
@@ -1011,18 +1012,20 @@ class ClusterSim:
                 sel = touched or []
             else:
                 sel = None
+            ids = []
             if sel is None or sel:
                 ids, ranks = self.sched.priorities_arrays(self.now, sel)
+            with span("rekey"):
                 if ids:
                     idx = np.fromiter((self._app_ai[i] for i in ids),
                                       np.int64, count=len(ids))
                     self._rank_arr[idx] = ranks
-            if not subset and not task_level and not static:
-                # task-level keys are rank-independent and static ranks are
-                # push-time-final: those queues never need re-keying;
-                # everyone else re-keys in one gather
-                for wq in self.waiting.values():
-                    wq.rebuild(self._rank_arr)
+                if not subset and not task_level and not static:
+                    # task-level keys are rank-independent and static ranks
+                    # are push-time-final: those queues never need
+                    # re-keying; everyone else re-keys in one gather
+                    for wq in self.waiting.values():
+                        wq.rebuild(self._rank_arr)
         else:
             if subset:
                 self._ranks.update(self.sched.priorities(self.now,

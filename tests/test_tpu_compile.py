@@ -12,6 +12,7 @@ The topology is described in a module-scoped fixture, never at import:
 only the test worker that runs this file may load the TPU library.
 """
 import os
+import re
 
 import pytest
 
@@ -61,9 +62,12 @@ def _args(sharding, n_apps, *, overrides, posterior):
     return rows
 
 
-def _compile(fn, rows):
+def _compile(fn, rows, kernel):
     compiled = jax.jit(fn).lower(rows).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name is its custom call's, and so the device trace's
+    assert re.search(rf"%{kernel}(\.\d+)? = ", text)
     return compiled
 
 
@@ -88,7 +92,7 @@ def test_ranked_kernel_compiles(one_chip, walkers, overrides, arrivals,
             po_scale=r.get("po_scale"))
         return out["ranks"], out["probs"], out["edges"], out.get("a_hist")
 
-    _compile(fn, rows)
+    _compile(fn, rows, "pdgraph_walk_ranked")
 
 
 @pytest.mark.parametrize("walkers,overrides,n_apps", [
@@ -107,4 +111,4 @@ def test_walk_phases_compile(one_chip, walkers, overrides, n_apps):
             interpret=False, track_arrivals=True)
         return total, arr, spill
 
-    _compile(fn, rows)
+    _compile(fn, rows, "pdgraph_walk")
