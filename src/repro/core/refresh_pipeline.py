@@ -40,6 +40,7 @@ to the walk-time trigger.  The multi-device mesh front-end lives in
 """
 from __future__ import annotations
 
+from collections import OrderedDict
 from dataclasses import dataclass
 from functools import lru_cache, partial
 from typing import Optional, Tuple
@@ -502,16 +503,19 @@ class FusedRefresh:
 
 
 def _prewarm_args(packed, prewarm_table, io=None):
-    """The ``PrewarmTable`` constants, uploaded through ``io`` (a
+    """The ``PrewarmTable`` constants, resident on the device: uploaded once
+    per table object (a KB repack builds a new one) through ``io`` (a
     :class:`_Crossings`; a fresh one counts for no one)."""
     io = io or _Crossings()
     if prewarm_table is not None:
-        return (io.put(prewarm_table.unit_class),
-                io.put(prewarm_table.warmup))
+        t = prewarm_table
+        return io.resident(("prewarm", id(t)),
+                           lambda: (t.unit_class, t.warmup), source=t)
     # 1-class placeholders keep the arg list static-shape friendly
-    return (io.make(jnp.full, (packed.samples.shape[0], packed.n_units, 1),
-                    -1, jnp.int32),
-            io.make(jnp.zeros, (1,), jnp.float32))
+    G, U = packed.samples.shape[0], packed.n_units
+    return io.resident(("prewarm", G, U),
+                       lambda: (np.full((G, U, 1), -1, np.int32),
+                                np.zeros(1, np.float32)))
 
 
 def _ranked_args(packed: PackedKB, walker: str, impl: Optional[str],
@@ -547,8 +551,8 @@ def _quant_dummies():
 def _dispatch_rows(qs: QueueState, slots: np.ndarray, packed: PackedKB,
                    prewarm_table, pad_to: Optional[int] = None, io=None):
     """Shared host-side marshalling for the refresh entry points: padded
-    row gather, override-width trim, prewarm constants (uploaded through
-    ``io``)."""
+    row gather (host arrays), override-width trim, prewarm constants
+    (resident, through ``io``)."""
     gi, start, executed, attained, kid, rid, stretch, ovs, ovc = \
         qs.gather(slots, pad_to=pad_to)
     with_ov = qs.override_apps > 0
@@ -606,20 +610,20 @@ def refresh_ranks_fused(packed: PackedKB, qs: QueueState, base_key, seed,
               if prewarm_table is not None else None)
         tri = zs if with_triage else None
         return FusedRefresh(zs, z, z, 0, zt, zt, tri, tri, tri)
+    io = _Crossings()
     gi, start, executed, attained, kid, rid, stretch, ovs, ovc, with_ov, \
-        uc, wt = _dispatch_rows(qs, slots, packed, prewarm_table)
+        uc, wt = _dispatch_rows(qs, slots, packed, prewarm_table, io=io)
     with_pw = prewarm_table is not None
     rank_in_kernel, qsv, qic = _ranked_args(packed, walker, impl,
                                             rank_in_kernel)
+    # the row arrays go to the jit as host arrays (its C++ argument path)
     ranks, probs, edges, spill, trigger, reach, sup, opt, mean = \
         _fused_pipeline(
             packed.samples, packed.counts, packed.cum_trans,
-            jnp.asarray(gi), jnp.asarray(start), jnp.asarray(executed),
-            jnp.asarray(attained), jnp.asarray(kid), jnp.asarray(rid),
-            base_key, np.uint32(int(seed) & 0xFFFFFFFF),
-            jnp.asarray(ovs), jnp.asarray(ovc),
-            jnp.asarray(np.arange(len(gi)) < A), jnp.asarray(stretch),
-            uc, wt, jnp.float32(prewarm_k), qsv, qic,
+            gi, start, executed, attained, kid, rid,
+            base_key, np.uint32(int(seed) & 0xFFFFFFFF), ovs, ovc,
+            np.arange(len(gi)) < A, stretch,
+            uc, wt, io.scalar(prewarm_k), qsv, qic,
             n_walkers=n_walkers, max_steps=max_steps, n_buckets=n_buckets,
             walker=walker, impl=impl, with_overrides=with_ov,
             compact_after=compact_after, compact_shrink=compact_shrink,
@@ -651,22 +655,51 @@ class DeltaTick:
     d2h: int = 0
 
 
+# Device copies of the dispatch constants, most recently used last: key ->
+# (source object or None, arrays).  An id-keyed entry holds its source, so
+# a recycled id cannot alias a freed table.
+_RESIDENT: "OrderedDict[tuple, tuple]" = OrderedDict()
+# entries kept before the least recently used is evicted: a few KB
+# generations' prewarm tables besides the placeholders and K values in use
+_RESIDENT_CAP = 32
+
+
 class _Crossings:
-    """Counts one dispatch's host<->device transfers: every upload goes
-    through ``put`` (a host array) or ``make`` (a one-scalar constant built
-    on the device, such as ``jnp.zeros``, whose fill value crosses), every
-    read-back through ``get``."""
+    """Counts one dispatch's host<->device transfers.  A per-dispatch host
+    array goes through ``put``, which hands it back unchanged for the jit
+    to upload on its C++ argument path; a constant comes from ``resident``
+    (``zeros``, ``scalar``), which uploads it only when the process-wide
+    cache misses; every read-back goes through ``get``."""
 
     def __init__(self):
         self.h2d = self.d2h = 0
 
     def put(self, x):
         self.h2d += 1
-        return jnp.asarray(x)
+        return x
 
-    def make(self, fn, *args):
-        self.h2d += 1
-        return fn(*args)
+    def resident(self, key, host, source=None):
+        """Device copies of the host arrays ``host()`` returns, uploaded on
+        the first call for ``key`` (and ``source``) and counted there."""
+        ent = _RESIDENT.get(key)
+        if ent is None or ent[0] is not source:
+            ent = (source, tuple(jax.device_put(a) for a in host()))
+            self.h2d += len(ent[1])
+            _RESIDENT[key] = ent
+            if len(_RESIDENT) > _RESIDENT_CAP:
+                _RESIDENT.popitem(last=False)
+        _RESIDENT.move_to_end(key)
+        return ent[1]
+
+    def zeros(self, *shape, dtype=np.float32):
+        """A resident zero placeholder for a disabled argument slot."""
+        return self.resident(("zeros", shape, np.dtype(dtype)),
+                             lambda: (np.zeros(shape, dtype),))[0]
+
+    def scalar(self, value):
+        """A resident float32 scalar (the prewarm K)."""
+        return self.resident(("scalar", float(value)),
+                             lambda: (np.float32(value),))[0]
 
     def get(self, x):
         self.d2h += 1
@@ -716,7 +749,9 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
     gather and uploads, up to the enqueue), ``.wait`` (every read of the
     results) and ``.consume`` (the host mirrors), with ``<path>`` ``tick``
     for ``retrigger`` and ``event`` otherwise; the returned tick counts
-    the uploads (``h2d``) and reads (``d2h``)."""
+    the uploads (``h2d``) and reads (``d2h``).  The row arrays reach the
+    jit as host arrays; the constants (prewarm tables, K, placeholders)
+    stay resident on the device after their first upload."""
     if qs.n_shards != 1:
         raise ValueError("refresh_ranks_delta serves 1-shard arenas; "
                          "mesh-sharded stores go through refresh_ranks_mesh")
@@ -738,8 +773,7 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
                     qs.d_probs, qs.d_edges, att_all,
                     qs.a_hist, qs.a_lo, qs.a_span, qs.a_reach,
                     gi_all, delta_all, stretch_all,
-                    uc, wt, io.make(jnp.float32, prewarm_k),
-                    n_walkers=n_walkers)
+                    uc, wt, io.scalar(prewarm_k), n_walkers=n_walkers)
                 out = {"ranks": ranks, "trigger": trigger, "reach": reach}
             else:
                 out = {"ranks": gittins_rank_hist(qs.d_probs, qs.d_edges,
@@ -758,15 +792,14 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
                 gi_all, delta_all, stretch_all = \
                     _retrigger_rows(qs, walked, io)
             else:
-                z = io.make(jnp.zeros, (1,), jnp.float32)
+                z = io.zeros(1)
                 gi_all, delta_all, stretch_all = \
-                    io.make(jnp.zeros, (1,), jnp.int32), z, z
-            dummy = io.make(jnp.zeros, (1, 1), jnp.float32)
+                    io.zeros(1, dtype=np.int32), z, z
+            dummy = io.zeros(1, 1)
             with_po = posterior is not None
             if with_po:
                 qs.ensure_posterior_rows()
-            post = qs.post if with_po else \
-                io.make(jnp.zeros, (1, 1, 1), jnp.float32)
+            post = qs.post if with_po else io.zeros(1, 1, 1)
             rank_in_kernel, qsv, qic = _ranked_args(packed, walker, impl,
                                                     rank_in_kernel)
             (qs.d_probs, qs.d_edges, ranks, spill, sup, opt, mean,
@@ -775,8 +808,7 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
                     packed.samples, packed.counts, packed.cum_trans,
                     io.put(gi), io.put(start), io.put(executed),
                     io.put(attained), io.put(kid), io.put(rid),
-                    base_key,
-                    io.put(np.asarray(int(seed) & 0xFFFFFFFF, np.uint32)),
+                    base_key, io.put(np.uint32(int(seed) & 0xFFFFFFFF)),
                     io.put(ovs), io.put(ovc),
                     io.put(np.arange(ap) < D), io.put(stretch),
                     io.put(slot_idx), qs.d_probs, qs.d_edges, att_all,
@@ -785,7 +817,7 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
                     qs.a_span if with_pw else dummy,
                     qs.a_reach if with_pw else dummy,
                     gi_all, delta_all, stretch_all,
-                    uc, wt, io.make(jnp.float32, prewarm_k), post, qsv, qic,
+                    uc, wt, io.scalar(prewarm_k), post, qsv, qic,
                     n_walkers=n_walkers, max_steps=max_steps,
                     n_buckets=n_buckets, walker=walker, impl=impl,
                     with_overrides=with_ov, compact_after=compact_after,
