@@ -12,10 +12,17 @@ Three things are compared, once the window has closed:
   overrides, walk counters, and the service credited through the
   scheduler's ``on_progress`` events, summed by the harness), and ranks
   the arena from its own rows for the walked slots and the arena's rows
-  for the rest;
-* the dirty set: at every full tick of the window, each live application
+  for the rest.  Where the dispatch returned the deadline triage
+  (Hermes-DDL), the reference's triage of the walked rows is compared,
+  and so is the class of every live row: the program's is the key the
+  simulator ranks its waiting queues by after the refresh, over 10**6;
+  the reference's comes from its own triage for the walked rows and the
+  arena's for the rest, the trace's deadline, the dispatch's time and the
+  credited service;
+* the dirty set: at every dispatch of the window that drains the whole
+  dirty set (the scheduler's call without ids), each live application
   that was admitted, started or finished a unit, or was requeued since
-  its last walk has to be walked by that tick;
+  its last walk has to be walked by that dispatch;
 * the engine: at the close, no slot of a pool is free while work of its
   kind waits, and no waiting task holds a better key than a running task
   that could be preempted.
@@ -26,7 +33,13 @@ Numbers compared (each with its limit in ``bench/limits.json``):
                   edges and the reference's;
 ``rank_gap``      the widest relative gap between the dispatch's ranks and
                   the reference's, over every live row of the arena;
-``stale_rows``    live applications a full tick of the window left
+``triage_gap``    the widest relative gap between a walked row's triage
+                  scalars (P90, P10, mean) and the reference's (0 on a
+                  dispatch without triage);
+``class_flips``   live rows whose deadline class differs from the
+                  reference's, leaving out rows whose reference slack lies
+                  within ``CLASS_MARGIN_S`` of a class boundary (exact: 0);
+``stale_rows``    live applications a draining dispatch of the window left
                   unwalked although their position moved (exact: 0);
 ``dispatch_violations``  the engine's breaches at the close (exact: 0).
 
@@ -43,8 +56,18 @@ import numpy as np
 
 from bench import reference
 
-NUMBERS = ("edge_gap", "rank_gap", "stale_rows", "dispatch_violations")
-EXACT = ("stale_rows", "dispatch_violations")     # counts, held to 0
+PER_DISPATCH = ("edge_gap", "rank_gap", "triage_gap", "class_flips")
+RUN_RULES = ("stale_rows", "dispatch_violations")  # counted by the harness
+NUMBERS = PER_DISPATCH + RUN_RULES
+COUNTS = ("class_flips",)                          # summed over dispatches
+CLASS_SPAN = 1e6          # the simulator's key is class * 10**6 + rank
+# The program's class takes the service credited in float64, the
+# reference's the float32 sums the arena keeps; over some hundred events
+# at up to 10**4 s the two part by a few milliseconds, and float32 P90 /
+# P10 values at that scale round at about 1e-3 s.  A row whose reference
+# slack lies within this margin of 0 or of the risk window could flip on
+# rounding alone, so it is not counted.
+CLASS_MARGIN_S = 0.05
 
 
 @dataclasses.dataclass
@@ -58,6 +81,12 @@ class Capture:
     ranks: np.ndarray               # (capacity,) ranks it returned
     attained: np.ndarray            # (capacity,) credited service per slot
     occupied: np.ndarray            # slots live at the dispatch
+    # with the deadline triage (None without):
+    triage: Optional[np.ndarray] = None     # (3, walked) sup, opt, mean
+    arena_triage: Optional[np.ndarray] = None   # (2, capacity) sup, opt
+    deadline: Optional[np.ndarray] = None   # (capacity,) from the trace
+    now: float = 0.0                        # simulated time of the dispatch
+    key_class: Optional[np.ndarray] = None  # (capacity,) the program's class
 
 
 def _rel(a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -76,18 +105,19 @@ def compare_capture(cap: Capture, tables: Dict, *, seed: int, n_walkers: int,
     precision) takes the program's place: it is compared against the
     reference at full precision on the same inputs."""
     rows = reference.rows_from_apps(tables, cap.apps)
-    ref_p, ref_e = reference.refresh_rows(tables, rows, seed=seed,
-                                          n_walkers=n_walkers,
-                                          n_buckets=n_buckets)
+    tri = cap.triage is not None
+    kw = dict(seed=seed, n_walkers=n_walkers, n_buckets=n_buckets,
+              with_triage=tri)
+    ref_p, ref_e, *ref_t = reference.refresh_rows(tables, rows, **kw)
     arena_p = np.asarray(cap.d_probs, np.float32)
     arena_e = np.asarray(cap.d_edges, np.float32)
     ranks = np.asarray(cap.ranks, np.float32)
     if precision == "highest":
         got_p, got_e = arena_p[cap.walked], arena_e[cap.walked]
+        got_t = [cap.triage] if tri else []
     else:
-        got_p, got_e = reference.refresh_rows(
-            tables, rows, seed=seed, n_walkers=n_walkers,
-            n_buckets=n_buckets, precision=precision)
+        got_p, got_e, *got_t = reference.refresh_rows(
+            tables, rows, precision=precision, **kw)
         arena_p, arena_e = arena_p.copy(), arena_e.copy()
         arena_p[cap.walked], arena_e[cap.walked] = got_p, got_e
         occ = cap.occupied
@@ -106,7 +136,11 @@ def compare_capture(cap: Capture, tables: Dict, *, seed: int, n_walkers: int,
     out = {"walker_moves": float(moves.max()) if len(moves) else 0.0,
            "edge_gap": float(egap.max()) if len(egap) else 0.0,
            "rank_gap": float(rgap.max()) if len(rgap) else 0.0,
+           "triage_gap": 0.0, "class_flips": 0.0,
            "rows": int(len(cap.walked)), "arena_rows": int(len(occ))}
+    if tri:
+        out.update(_compare_triage(cap, np.asarray(ref_t[0]),
+                                   np.asarray(got_t[0]), precision))
     if len(rgap):
         i = int(np.argmax(rgap))
         s = int(occ[i])
@@ -116,6 +150,31 @@ def compare_capture(cap: Capture, tables: Dict, *, seed: int, n_walkers: int,
             "attained": float(cap.attained[s]),
             "probs": arena_p[s].tolist(), "edges": arena_e[s].tolist()}
     return out
+
+
+def _compare_triage(cap: Capture, ref_t: np.ndarray, got_t: np.ndarray,
+                    precision: str) -> Dict:
+    """``triage_gap`` of the walked rows and ``class_flips`` of the live
+    rows of one dispatch with triage.  The reference's class takes its own
+    P90 / P10 for the walked rows and the arena's for the rest; the
+    control's (``precision`` not ``"highest"``) takes its own for the
+    walked rows, the program's class is read off its key."""
+    occ = cap.occupied
+    tgap = _rel(got_t, ref_t).max() if ref_t.size else 0.0
+
+    def classes(walked_t):
+        sup, opt = np.array(cap.arena_triage)
+        sup[cap.walked], opt[cap.walked] = walked_t[0], walked_t[1]
+        args = (sup[occ], opt[occ], cap.attained[occ], cap.deadline[occ],
+                cap.now)
+        return reference.ddl_class(*args), reference.slacks(*args)
+    want, (s_sup, s_opt) = classes(ref_t)
+    got = cap.key_class[occ] if precision == "highest" else classes(got_t)[0]
+    m = CLASS_MARGIN_S
+    near_opt = np.abs(s_opt) < m
+    near_sup = (s_opt >= m) & (np.abs(s_sup - reference.RISK_WINDOW_S) < m)
+    flips = (got != want) & ~(near_opt | near_sup)
+    return {"triage_gap": float(tgap), "class_flips": float(flips.sum())}
 
 
 def dispatch_violations(sim) -> int:
@@ -141,11 +200,12 @@ def dispatch_violations(sim) -> int:
 
 
 def combine(parts: List[Dict]) -> Dict[str, float]:
-    """The widest reading of each number over the sampled dispatches."""
-    out = {k: 0.0 for k in NUMBERS + ("walker_moves",) if k not in EXACT}
+    """The widest reading of each gap, and the sum of each count, over the
+    sampled dispatches."""
+    out = {k: 0.0 for k in PER_DISPATCH + ("walker_moves",)}
     for p in parts:
         for k in out:
-            out[k] = max(out[k], p[k])
+            out[k] = out[k] + p[k] if k in COUNTS else max(out[k], p[k])
     return out
 
 

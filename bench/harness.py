@@ -164,11 +164,15 @@ class CompileCounter:
 class Layers:
     """Wrappers, installed from here, around the calls into each layer.
 
-    * ``ClusterSim._refresh_ranks`` (instance): full ticks pass
-      ``touched=``, the event path an id list; marks the micro-batch;
+    * ``ClusterSim._refresh_ranks`` (instance): bucket ticks pass
+      ``touched=``, event batches an id list; marks the micro-batch, and
+      classes every dispatch made inside the call by that caller, whatever
+      the call re-ranks (an event batch of a policy whose ranks hang on
+      time re-ranks everything);
     * ``repro.core.scheduler.refresh_ranks_delta``: one refresh dispatch
       (its ``walked`` rows, spill, result), counted and sampled for the
-      check;
+      check; one with ``retrigger`` is the scheduler's call without ids,
+      which drains the whole dirty set;
     * the scheduler's application events (``on_arrivals``,
       ``on_unit_start``, ``on_unit_finish``, ``on_requeue``,
       ``on_progress``, ``on_app_complete``, ``on_app_shed``): which
@@ -179,15 +183,19 @@ class Layers:
       paths, the prewarm-plan apply and the reschedule.
     """
 
-    def __init__(self, sim, *, timed: bool, sample_seed: int):
+    def __init__(self, sim, *, timed: bool, sample_seed: int,
+                 deadlines: Optional[Dict[str, float]] = None):
         import jax
         from repro.core import scheduler
         self.sim, self.timed = sim, timed
+        self.deadlines = deadlines or {}    # app id -> the trace's deadline
         self.jax = jax
         self._sched_mod = scheduler
         self._orig_delta = scheduler.refresh_ranks_delta
         self.in_window = False
         self.batch_full = False
+        self.caller: Optional[str] = None   # "tick" | "event" while in a call
+        self._keyed: List = []              # captures awaiting the keys
         self.rng = np.random.default_rng(sample_seed)
         self.due: set = set()               # apps due a walk
         self.credit: Dict[str, np.ndarray] = {}   # [in unit, total] float32
@@ -209,6 +217,7 @@ class Layers:
         self.event_caps: List = []
         self.n_events_seen = 0
         self.stale = 0
+        self.bucket_ticks = 0
 
     @contextlib.contextmanager
     def span(self, name):
@@ -227,11 +236,20 @@ class Layers:
             full = app_ids is None
             if full:
                 layers.batch_full = True
+                # a tick over no live application dispatches nothing
+                layers.bucket_ticks += layers.in_window and bool(
+                    layers.credit)
+            layers.caller = "tick" if full else "event"
             t0 = time.perf_counter()
-            with layers.span("full_tick" if full else "event_refresh"):
-                out = orig_refresh(app_ids, touched=touched)
+            try:
+                with layers.span("full_tick" if full else "event_refresh"):
+                    out = orig_refresh(app_ids, touched=touched)
+            finally:
+                layers.caller = None
             if layers.in_window and layers.timed and not full:
                 layers.event_refresh_s.append(time.perf_counter() - t0)
+            if layers._keyed:
+                layers._read_keys()
             return out
 
         sim._refresh_ranks = refresh_ranks
@@ -245,7 +263,9 @@ class Layers:
 
         def refresh_ranks_delta(packed, qs, base_key, seed, **kw):
             tick = layers._orig_delta(packed, qs, base_key, seed, **kw)
-            layers._walked(qs, tick, bool(kw.get("retrigger", True)))
+            layers._walked(qs, tick, drains_all=bool(kw.get("retrigger",
+                                                            True)),
+                           triage=bool(kw.get("with_triage")))
             return tick
 
         self._sched_mod.refresh_ranks_delta = refresh_ranks_delta
@@ -313,34 +333,36 @@ class Layers:
         self._sched_mod.refresh_ranks_delta = self._orig_delta
 
     # -------------------------------------------------------- window count
-    def _walked(self, qs, tick, full: bool):
+    def _walked(self, qs, tick, *, drains_all: bool, triage: bool):
         """After one dispatch: the walked applications are no longer due;
-        at a full tick of the window every live application still due is
-        a stale row."""
+        at a dispatch of the window that drains the whole dirty set every
+        live application still due is a stale row.  The dispatch is a
+        tick's or an event's as its caller was."""
         if self.warming:
             return
         ids = [qs.ids[int(s)] for s in tick.walked]
         self.due.difference_update(ids)
         if not self.in_window:
             return
-        if full:
+        if drains_all:
             self.stale += len(self.due)
         self.calls += 1
         D = len(ids)
         self.rows_walked += D
         if tick.spill:
             self.spilled += 1
-        if full:
+        triage = triage and D > 0
+        if self.caller == "tick":
             self.tick_rows.append(D)
             # every full tick is captured: the last one is always checked
-            self.last_tick = self._capture(qs, tick, ids, "tick")
+            self.last_tick = self._capture(qs, tick, ids, "tick", triage)
             self.n_ticks_seen = self._keep(self.tick_caps, self.n_ticks_seen,
                                            TICK_CAPTURES,
                                            lambda: self.last_tick)
         else:
             self.n_events_seen = self._keep(
                 self.event_caps, self.n_events_seen, EVENT_CAPTURES,
-                lambda: self._capture(qs, tick, ids, "event"))
+                lambda: self._capture(qs, tick, ids, "event", triage))
 
     def _keep(self, caps: List, seen: int, k: int, capture) -> int:
         """Reservoir-sample one more dispatch into ``caps``; returns the
@@ -366,16 +388,39 @@ class Layers:
                 "key_id": run.key_id, "refresh_id": run.refreshes,
                 "overrides": dict(run.overrides)}
 
-    def _capture(self, qs, tick, ids, kind):
+    def _capture(self, qs, tick, ids, kind, triage: bool):
         occ = qs.occupied()
         attained = np.zeros(len(qs.ids), np.float32)
         attained[occ] = [self.credit[qs.ids[int(s)]][1] for s in occ]
-        return check.Capture(kind=kind,
-                             walked=np.asarray(tick.walked, np.int64),
-                             apps=[self._record(i) for i in ids],
-                             d_probs=qs.d_probs, d_edges=qs.d_edges,
-                             ranks=np.asarray(tick.ranks),
-                             attained=attained, occupied=occ)
+        walked = np.asarray(tick.walked, np.int64)
+        cap = check.Capture(kind=kind, walked=walked,
+                            apps=[self._record(i) for i in ids],
+                            d_probs=qs.d_probs, d_edges=qs.d_edges,
+                            ranks=np.asarray(tick.ranks),
+                            attained=attained, occupied=occ)
+        if triage:
+            # the triage the dispatch wrote into the arena's mirrors; the
+            # program's class is read off its keys once the call returns
+            cap.triage = np.stack([qs.sup[walked], qs.opt[walked],
+                                   qs.mean[walked]])
+            cap.arena_triage = np.stack([qs.sup, qs.opt]).copy()
+            cap.deadline = np.full(len(qs.ids), np.inf)
+            for s in occ:
+                d = self.deadlines.get(qs.ids[int(s)])
+                cap.deadline[s] = np.inf if d is None else d
+            cap.now = float(self.sim.now)
+            self._keyed.append((cap, [qs.ids[int(s)] for s in occ]))
+        return cap
+
+    def _read_keys(self):
+        """The class of each live row of the captures made in the call
+        that just returned: the simulator's waiting-queue key over 10**6."""
+        sim = self.sim
+        for cap, app_ids in self._keyed:
+            cap.key_class = np.full(len(cap.attained), -1, np.int64)
+            keys = sim._rank_arr[[sim._app_ai[i] for i in app_ids]]
+            cap.key_class[cap.occupied] = np.floor(keys / check.CLASS_SPAN)
+        self._keyed.clear()
 
     def warm_shapes(self, trace, rows: int) -> int:
         """Dispatch, on a copy of the scheduler and through its own
@@ -460,6 +505,18 @@ class Layers:
 
 
 # ------------------------------------------------------------------- run
+def program_counters(sim) -> Dict[str, int]:
+    """The scheduler's own counters (``refresh_stats``), as they stand;
+    empty on a program without them."""
+    return dict(getattr(sim.sched, "refresh_stats", {}))
+
+
+def window_counters(at_open: Dict[str, int], at_close: Dict[str, int]
+                    ) -> Dict[str, int]:
+    """What the window added to each counter."""
+    return {k: v - at_open.get(k, 0) for k, v in at_close.items()}
+
+
 def build_sim(config: Dict, kb, sim_seed: int):
     from repro.core.refresh_config import RefreshConfig
     from repro.serving.simulator import ClusterSim, SimConfig
@@ -514,7 +571,9 @@ def run_cell(config: Dict, traffic: Dict, *, seed: int, seconds: float,
     sim = build_sim(config, kb, seeds["sim"])
     if steer is not None:
         steer(sim)
-    layers = Layers(sim, timed=trace, sample_seed=seeds["sample"])
+    layers = Layers(sim, timed=trace, sample_seed=seeds["sample"],
+                    deadlines={i.app_id: i.deadline for i in insts
+                               if i.deadline is not None})
     warm = traffic["warmup"]
     st = {"phase": "setup", "ticks": 0, "quiet": 0,
           "lowered_at_tick": compiles.lowered, "batches": [],
@@ -542,7 +601,8 @@ def run_cell(config: Dict, traffic: Dict, *, seed: int, seconds: float,
         jax.config.update("jax_log_compiles", True)   # name what compiles
         st.update(phase="window", events0=s.events_processed,
                   policy0=s.policy_time, now0=s.now,
-                  live0=len(layers.credit), compiles0=compiles.snapshot())
+                  live0=len(layers.credit), compiles0=compiles.snapshot(),
+                  counters0=program_counters(s))
         st["t0"] = st["t_prev"] = time.perf_counter()
         st["setup_s"] = st["t0"] - t_start
 
@@ -557,6 +617,7 @@ def run_cell(config: Dict, traffic: Dict, *, seed: int, seconds: float,
                 st["tick_batches"].append(dt)
             if now - st["t0"] >= seconds:
                 st["t1"] = now
+                st["counters1"] = program_counters(s)
                 return True
             return False
         if now - t_start > SETUP_LIMIT_S:
@@ -609,6 +670,8 @@ def run_cell(config: Dict, traffic: Dict, *, seed: int, seconds: float,
         "event_refresh_s": np.asarray(layers.event_refresh_s),
         "tick_rows": np.asarray(layers.tick_rows),
         "rows_walked": layers.rows_walked,
+        "bucket_ticks": layers.bucket_ticks,
+        "counters": window_counters(st["counters0"], st["counters1"]),
         "attempted": layers.calls, "spilled": layers.spilled,
         "kernel_ok": kernel_ok,
         "compiles": {"lowered": lowered, "cache_hits": hits,
@@ -627,6 +690,9 @@ def run_cell(config: Dict, traffic: Dict, *, seed: int, seconds: float,
         f"{len(rec['tick_batches'])} with a full tick, {events} events, "
         f"{rec['attempted']} refresh dispatches, {rec['rows_walked']} rows "
         "walked")
+    log(f"window: {rec['bucket_ticks']} bucket ticks over live "
+        f"applications, {len(rec['tick_rows'])} tick dispatches; program "
+        f"counters {json.dumps(rec['counters'])}")
     log(f"window compiles: lowered={lowered} cache_hits={hits} "
         f"compiled={misses}")
     log(f"kernel: LAST_DISPATCH={ops.LAST_DISPATCH!r} "

@@ -1,0 +1,10 @@
+"""The prepare phase of an event batch's refresh dispatch, in ms: the
+program's ``hermes.event.prepare`` spans (host work up to the enqueue:
+the scheduler's dirty-set drain, the row gather, every upload), summed
+over the traced window and divided by its ``hermes.event.wait`` spans,
+one a dispatch."""
+from bench.trace_reduce import phase_ms
+
+
+def read(rec):
+    return phase_ms(rec, "event", "prepare")

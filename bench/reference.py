@@ -1,5 +1,6 @@
 """Plain reference of the Hermes refresh: the PDGraph Monte-Carlo walk,
-the demand histogram and the Gittins rank, in numpy float32.
+the demand histogram, the Gittins rank, and the deadline triage of
+Hermes-DDL (arXiv 2506.14851 §3.3), in numpy float32.
 
 It imports nothing of the program.  Its walk tables are built here from
 the knowledge base's raw profiling records (per-unit token counts,
@@ -10,7 +11,10 @@ semantics of the counter-RNG walker (murmur3 finalizer over a per-walker
 Weyl counter, one hash per step: demand-sample index from the high 16
 bits, transition draw from the low 16), of the floor-binned histogram and
 of the Gittins index over bucket midpoints, one float32 operation at a
-time, each sum left to right.
+time, each sum left to right.  The triage takes the P90 and P10 of each
+row's walker totals by linear interpolation between order statistics, and
+their mean; the class compares the slack those leave before the deadline
+with nought and with the 30 s risk window.
 
 ``precision="high"`` is the control: every table value the walk selects
 (sample, count, transition CDF) is first rounded to what a three-pass
@@ -31,6 +35,8 @@ GOLDEN = U32(0x9E3779B9)
 U16_SCALE = F32(1.0 / 65536.0)
 MAX_STEPS = 64
 NO_RANK = F32(1e30)        # the rank of a row with no bucket left to finish
+SUP_Q, OPT_Q = 0.9, 0.1    # worst-case and optimistic demand quantiles
+RISK_WINDOW_S = 30.0       # slack under which a feasible deadline is at risk
 
 
 def to_bf16(x: np.ndarray) -> np.ndarray:
@@ -208,6 +214,45 @@ def histogram(total: np.ndarray, n_buckets: int):
     return probs, edges
 
 
+# ------------------------------------------------------------------ triage
+def _quantile(srt: np.ndarray, q: float) -> np.ndarray:
+    """Row quantile of sorted rows: ``lo + (hi - lo) * frac`` at position
+    ``q * (W - 1)``, in float32."""
+    pos = q * (srt.shape[1] - 1)
+    k = int(np.floor(pos))
+    frac = F32(pos - k)
+    lo = srt[:, k]
+    hi = srt[:, min(k + 1, srt.shape[1] - 1)]
+    return (lo + ((hi - lo) * frac).astype(F32)).astype(F32)
+
+
+def triage(total: np.ndarray):
+    """``(sup, opt, mean)`` of each row of walker totals ``(A, W)``: the
+    P90, the P10 and the mean, float32."""
+    total = np.asarray(total, F32)
+    srt = np.sort(total, axis=1)
+    return (_quantile(srt, SUP_Q), _quantile(srt, OPT_Q),
+            total.mean(axis=1, dtype=F32))
+
+
+def slacks(sup, opt, attained, deadline, now: float):
+    """Seconds left before the deadline once the P90 (``sup``) and the P10
+    (``opt``) demand is served: ``deadline - now - max(q - attained, 0)``,
+    float64; an application without a deadline (inf) has infinite slack."""
+    att = np.asarray(attained, np.float64)
+    ddl = np.asarray(deadline, np.float64)
+    return tuple(ddl - now - np.maximum(np.asarray(q, np.float64) - att, 0.0)
+                 for q in (sup, opt))
+
+
+def ddl_class(sup, opt, attained, deadline, now: float) -> np.ndarray:
+    """§3.3 class of each application: 2 (hopeless) when even the
+    optimistic demand misses the deadline, 0 (at risk) when the P90 slack
+    is under the risk window, 1 (safe) otherwise."""
+    s_sup, s_opt = slacks(sup, opt, attained, deadline, now)
+    return np.where(s_opt < 0.0, 2, np.where(s_sup < RISK_WINDOW_S, 0, 1))
+
+
 # -------------------------------------------------------------------- rank
 def _sum_lr(x: np.ndarray) -> np.ndarray:
     acc = x[..., 0].copy()
@@ -287,14 +332,16 @@ def _override_rows(tables: Dict, graph: np.ndarray, overrides: Sequence):
 
 def refresh_rows(tables: Dict, rows: Dict, *, seed: int, n_walkers: int,
                  n_buckets: int, precision: str = "highest",
-                 block: int = 256):
+                 block: int = 256, with_triage: bool = False):
     """Walk and histogram the rows in blocks: returns ``probs, edges`` for
     every row of ``rows`` (``graph``, ``start``, ``executed``,
     ``attained``, ``key_id``, ``refresh_id``, and either ``overrides``, a
-    dict per row, or ``ov_samples`` / ``ov_counts`` arrays)."""
+    dict per row, or ``ov_samples`` / ``ov_counts`` arrays), and with
+    ``with_triage`` a third item, ``(sup, opt, mean)`` of every row."""
     n = len(rows["graph"])
     probs = np.zeros((n, n_buckets), F32)
     edges = np.zeros((n, n_buckets), F32)
+    tri = np.zeros((3, n), F32)
     st = streams(seed, rows["key_id"], rows["refresh_id"])
     for b in range(0, n, block):
         sl = slice(b, min(b + block, n))
@@ -311,4 +358,6 @@ def refresh_rows(tables: Dict, rows: Dict, *, seed: int, n_walkers: int,
         total = (np.asarray(rows["attained"][sl], F32)[:, None]
                  + np.maximum(rem, F32(0))).astype(F32)
         probs[sl], edges[sl] = histogram(total, n_buckets)
-    return probs, edges
+        if with_triage:
+            tri[:, sl] = triage(total)
+    return (probs, edges, tuple(tri)) if with_triage else (probs, edges)

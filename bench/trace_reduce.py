@@ -6,9 +6,13 @@ the per-layer metrics read.
   (the ``XLA Ops`` line of each ``/device:TPU:<n>`` plane), clipped to the
   window and averaged over the devices; idle = window - busy;
 * per-operation device time, summed by operation name;
-* each idle gap of the device, labelled by the innermost harness span
-  (``bench.<name>``) on the host that covers the gap's midpoint, summed by
-  label (``engine`` where no span covers it: the simulator's own loop).
+* each idle gap of the device, labelled by the innermost host span that
+  covers the gap's midpoint, summed by label: a harness span
+  (``bench.<name>``) by its name without the prefix, a program span
+  (``hermes.<name>``) by its full name, ``engine`` where no span covers
+  it (the simulator's own loop);
+* the program's spans that lie wholly inside the window, counted and
+  summed by name (``spans``: ``{name: {"n": count, "s": seconds}}``).
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 SPAN = "bench."
+PROGRAM_SPAN = "hermes."
 WINDOW = SPAN + "window"
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
@@ -85,16 +90,43 @@ def label_gaps(gap_list: Sequence[Tuple[float, float]],
     return out
 
 
+def program_spans(host: List[Tuple[str, float, float]], lo: float,
+                  hi: float) -> Dict[str, Dict[str, float]]:
+    """Count and seconds, by name, of the program's spans that start and
+    end inside [lo, hi]."""
+    out: Dict[str, Dict[str, float]] = {}
+    for n, s, d in host:
+        if n.startswith(PROGRAM_SPAN) and s >= lo and s + d <= hi:
+            e = out.setdefault(n, {"n": 0, "s": 0.0})
+            e["n"] += 1
+            e["s"] += d * 1e-9
+    return out
+
+
+def phase_ms(rec: Dict, path: str, phase: str) -> Optional[float]:
+    """Milliseconds of the program's ``hermes.<path>.<phase>`` spans per
+    refresh dispatch of that path in the traced window: their sum over the
+    count of ``hermes.<path>.wait`` spans (one a dispatch); None where the
+    run has no trace or the path no dispatch."""
+    spans = (rec.get("trace") or {}).get("spans", {})
+    n = spans.get(f"{PROGRAM_SPAN}{path}.wait", {}).get("n", 0)
+    if not n:
+        return None
+    return spans.get(f"{PROGRAM_SPAN}{path}.{phase}", {}).get("s", 0.0) \
+        / n * 1e3
+
+
 def reduce_events(device: Dict[str, List[Tuple[str, float, float]]],
                   host: List[Tuple[str, float, float]]) -> Dict:
     """``device``: plane -> [(op name, start ns, duration ns)];
-    ``host``: [(span name, start ns, duration ns)] of the harness spans."""
+    ``host``: [(span name, start ns, duration ns)] of the harness's and
+    the program's spans."""
     win = [(s, s + d) for n, s, d in host if n == WINDOW]
     if not win:
         raise ValueError("the trace holds no bench.window span")
     lo, hi = win[0]
-    spans = [(s, s + d, n[len(SPAN):]) for n, s, d in host
-             if n.startswith(SPAN) and n != WINDOW]
+    spans = [(s, s + d, n[len(SPAN):] if n.startswith(SPAN) else n)
+             for n, s, d in host if n != WINDOW]
     op_ns: Dict[str, float] = {}
     busy, idle_by = [], {}
     n_ops = 0
@@ -114,6 +146,7 @@ def reduce_events(device: Dict[str, List[Tuple[str, float, float]]],
     idle = sorted(idle_by.items(), key=lambda kv: -kv[1])[:TOP]
     return {"window_s": window_s, "busy_s": busy_s, "n_ops": n_ops,
             "op_s": {k: v * 1e-9 for k, v in op_ns.items()},
+            "spans": program_spans(host, lo, hi),
             "breakdown": {"device_ops": [[k, v * 1e-9] for k, v in ops],
                           "idle_gaps": [[k, v] for k, v in idle]}}
 
@@ -141,7 +174,8 @@ def label_ops(ops, modules):
 
 
 def read_xplane(path: str):
-    """(device events per plane, harness host spans) of one xplane file."""
+    """(device events per plane, the harness's and the program's host
+    spans) of one xplane file."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     device, host = {}, []
@@ -158,7 +192,7 @@ def read_xplane(path: str):
             for line in plane.lines:
                 host.extend((e.name, float(e.start_ns), float(e.duration_ns))
                             for e in line.events
-                            if e.name.startswith(SPAN))
+                            if e.name.startswith((SPAN, PROGRAM_SPAN)))
     return device, host
 
 

@@ -16,6 +16,7 @@ A traffic file holds::
     {"stream": {"process": "gamma", "cv": 2.5, "tenants": 16,
                 "load": 1.0, "duration_s": 900.0},
      "burst": {"n_apps": 12288, "waves": 1, "span_s": 1.0},   # optional
+     "deadlines": {"scales": [1.2, 1.5, 2.0], "share": 1.0},  # optional
      "demand_probe": {"n_probe": 2000, "seed": 0}}
 
 ``set_seed`` (optional, in ``stream``) fixes the stream for every run
@@ -31,6 +32,14 @@ seed draws the arrival times, names, tenants and trajectories.  The burst
 is a flash crowd of ``n_apps`` arrivals split evenly into ``waves``
 same-instant waves over the first ``span_s`` simulated seconds (each wave
 is admitted as one batch through the simulator's arrival path).
+
+``deadlines`` gives a share of the stream's applications a deadline, as
+``make_open_workload(with_deadlines=True)`` does (arXiv 2506.14851 Fig. 11):
+``arrival + scale * (true service + cold-start overhead)``, with the scale
+drawn uniformly from ``scales``.  Under ``set_seed`` each application keeps
+its ``scale * (service + overhead)`` and takes it to its new arrival time.
+Without the section no application carries a deadline, and the trace is
+the one the file gave before the section existed.
 """
 from __future__ import annotations
 
@@ -103,11 +112,15 @@ def open_workload(duration_s: float, *, t_in: float, t_out: float,
                   target_load: Optional[float] = None,
                   n_service_slots: int = 16, process: str = "poisson",
                   cv: float = 2.0, tenants: int = 8, seed: int = 0,
-                  max_apps: Optional[int] = None, suite=None):
-    """Copy of ``make_open_workload`` (uniform tenants, global mix, no
-    deadlines): the renewal process, then per-arrival tenant, name and
-    trajectory draws in the program's order."""
-    from repro.apps.spec import sample_trajectory
+                  max_apps: Optional[int] = None, suite=None,
+                  deadlines: Optional[Dict] = None):
+    """Copy of ``make_open_workload`` (uniform tenants, global mix): the
+    renewal process, then per-arrival tenant, name, deadline and
+    trajectory draws in the program's order.  Returns the instances and
+    each one's deadline budget ``scale * (service + overhead)`` (None
+    where it has no deadline)."""
+    from repro.apps.spec import coldstart_overhead, sample_trajectory, \
+        trajectory_service
     from repro.apps.suite import SUITE
     from repro.apps.workload import AppInstance
     if (rate_per_s is None) == (target_load is None):
@@ -128,10 +141,25 @@ def open_workload(duration_s: float, *, t_in: float, t_out: float,
     prof_idx = (rng.choice(n_ten, size=n, p=weights)
                 if n else np.zeros(0, np.int64))
     names = sample_app_names(n, rng) if n else []
-    return [AppInstance(app_id=f"app{i:06d}", app_name=name,
-                        tenant=f"tenant{prof_idx[i]}", arrival=float(t),
-                        trajectory=sample_trajectory(suite[name], rng))
-            for i, (t, name) in enumerate(zip(times, names))]
+    has_ddl = np.zeros(n, bool)
+    if deadlines and n:
+        scales = [float(x) for x in deadlines["scales"]]
+        has_ddl = rng.uniform(size=n) < float(deadlines.get("share", 1.0))
+        ddl_pick = rng.integers(len(scales), size=n)
+    insts, budgets = [], []
+    for i, (t, name) in enumerate(zip(times, names)):
+        traj = sample_trajectory(suite[name], rng)
+        budget = None
+        if has_ddl[i]:
+            budget = scales[int(ddl_pick[i])] * (
+                trajectory_service(traj, t_in, t_out)
+                + coldstart_overhead(suite[name], traj))
+        insts.append(AppInstance(
+            app_id=f"app{i:06d}", app_name=name,
+            tenant=f"tenant{prof_idx[i]}", arrival=float(t), trajectory=traj,
+            deadline=None if budget is None else float(t + budget)))
+        budgets.append(budget)
+    return insts, budgets
 
 
 def burst_workload(n_apps: int, waves: int, span_s: float, *, tenants: int,
@@ -167,15 +195,19 @@ def generate(traffic: Dict, *, n_llm_slots: int, t_in: float, t_out: float,
     rate = float(st["load"]) * n_llm_slots / e_s
     ss = np.random.SeedSequence(seed).generate_state(3)
     fixed = st.get("set_seed")
-    insts = open_workload(float(st["duration_s"]), t_in=t_in, t_out=t_out,
-                          rate_per_s=rate, process=st["process"],
-                          cv=float(st["cv"]), tenants=int(st["tenants"]),
-                          seed=int(ss[0]) if fixed is None else int(fixed))
+    insts, budgets = open_workload(
+        float(st["duration_s"]), t_in=t_in, t_out=t_out, rate_per_s=rate,
+        process=st["process"], cv=float(st["cv"]),
+        tenants=int(st["tenants"]),
+        seed=int(ss[0]) if fixed is None else int(fixed),
+        deadlines=traffic.get("deadlines"))
     if fixed is not None:
         order = np.random.default_rng(int(ss[2])).permutation(len(insts))
-        insts = [dataclasses.replace(insts[j], arrival=insts[i].arrival,
-                                     app_id=insts[i].app_id)
-                 for i, j in enumerate(order)]
+        insts = [dataclasses.replace(
+            insts[j], arrival=insts[i].arrival, app_id=insts[i].app_id,
+            deadline=None if budgets[j] is None
+            else float(insts[i].arrival + budgets[j]))
+            for i, j in enumerate(order)]
     b = traffic.get("burst")
     if b:
         insts = insts + burst_workload(int(b["n_apps"]), int(b["waves"]),
@@ -188,9 +220,10 @@ def generate(traffic: Dict, *, n_llm_slots: int, t_in: float, t_out: float,
 
 
 def same_trace(a: Sequence, b: Sequence) -> bool:
-    """True when two traces agree on every arrival time, app name, tenant
-    and trajectory."""
+    """True when two traces agree on every arrival time, app name, tenant,
+    trajectory and deadline."""
     return len(a) == len(b) and all(
         x.arrival == y.arrival and x.app_name == y.app_name
         and x.tenant == y.tenant and x.trajectory == y.trajectory
+        and x.deadline == y.deadline
         for x, y in zip(a, b))

@@ -109,7 +109,7 @@ def test_control_fails_the_limits(kb):
     assert sound["walker_moves"] == sound["edge_gap"] == \
         sound["rank_gap"] == 0.0
     assert ctl["rank_gap"] > limits["rank_gap"]
-    for k in check.EXACT:
+    for k in check.RUN_RULES:
         ctl[k] = sound[k] = 0.0
     assert check.verdict(sound, limits)
     assert not check.verdict(ctl, limits)
@@ -142,3 +142,57 @@ def test_three_pass_keeps_sixteen_bits():
     assert y[0] == 1.0 and y[1] == 273.0
     rel = np.abs(y - x) / x
     assert rel.max() < 2.0 ** -15 and rel[3] > 0
+
+
+def _totals(kb, rows):
+    p = pack_graphs(kb, T_IN, T_OUT)
+    st = walker_streams(np.uint32(SEED), jnp.asarray(rows["key_id"]),
+                        jnp.asarray(rows["refresh_id"]))
+    rem, _ = pdgraph_walk(p.samples, p.counts, p.cum_trans,
+                          jnp.asarray(rows["graph"]),
+                          jnp.asarray(rows["start"]),
+                          jnp.asarray(rows["executed"]), st,
+                          jnp.asarray(rows["ov_samples"]),
+                          jnp.asarray(rows["ov_counts"]), n_walkers=W,
+                          impl="ref", compact_schedule=())
+    return jnp.asarray(rows["attained"])[:, None] + jnp.maximum(rem, 0.0)
+
+
+def test_triage_follows_the_program(kb):
+    """P90 and P10 to the bit, the mean to float32 rounding, over the
+    reference walk's own totals; its control is off by more than the
+    limit."""
+    from repro.core.refresh_pipeline import _triage_stats
+    tables, apps, rows = _rows(kb, seed=2)
+    want = [np.asarray(x) for x in _triage_stats(_totals(kb, rows))]
+    kw = dict(seed=SEED, n_walkers=W, n_buckets=NB, block=7)
+    _, _, got = reference.refresh_rows(tables, rows, with_triage=True, **kw)
+    assert np.array_equal(got[0], want[0])
+    assert np.array_equal(got[1], want[1])
+    assert np.allclose(got[2], want[2], rtol=1e-6, atol=0)
+    gap = lambda t: max(check._rel(a, b).max()        # noqa: E731
+                        for a, b in zip(t, got))
+    assert gap(want) <= load_limits()["triage_gap"]
+    _, _, ctl = reference.refresh_rows(tables, rows, with_triage=True,
+                                       precision="high", **kw)
+    assert gap(ctl) > load_limits()["triage_gap"]
+
+
+def test_ddl_class_follows_the_policy():
+    """The reference's class is the deadline policy's key over 10**6."""
+    from repro.core.policies import HermesDDLPolicy
+    rng = np.random.default_rng(5)
+    n = 400
+    opt = rng.uniform(0, 200, n).astype(np.float32)
+    sup = (opt + rng.uniform(0, 200, n)).astype(np.float32)
+    att = rng.uniform(0, 150, n).astype(np.float32)
+    now = 1000.0
+    ddl = now + rng.uniform(-100, 400, n)
+    ddl[:20] = np.inf
+    keys = HermesDDLPolicy().ranks_columns(
+        now, g=rng.uniform(0, 50, n).astype(np.float32), sup=sup, opt=opt,
+        attained=att, deadline=ddl, mean=opt)
+    cls = reference.ddl_class(sup, opt, att, ddl, now)
+    assert np.array_equal(np.floor(keys / check.CLASS_SPAN), cls)
+    assert set(cls.tolist()) == {0, 1, 2}
+    assert (cls[:20] == 1).all()
