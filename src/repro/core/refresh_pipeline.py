@@ -726,7 +726,8 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
                         retrigger: bool = True,
                         with_triage: bool = False,
                         posterior=None,
-                        rank_in_kernel: Optional[bool] = None) -> DeltaTick:
+                        rank_in_kernel: Optional[bool] = None,
+                        path: Optional[str] = None) -> DeltaTick:
     """One delta tick over the slot store: walk ``walked`` (normally the
     drained dirty set), scatter their histogram rows into the device arena,
     re-rank every slot in place.  With an empty ``walked`` the tick is a
@@ -747,15 +748,16 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
 
     The dispatch runs in three host spans, ``hermes.<path>.prepare`` (row
     gather and uploads, up to the enqueue), ``.wait`` (every read of the
-    results) and ``.consume`` (the host mirrors), with ``<path>`` ``tick``
-    for ``retrigger`` and ``event`` otherwise; the returned tick counts
-    the uploads (``h2d``) and reads (``d2h``).  The row arrays reach the
-    jit as host arrays; the constants (prewarm tables, K, placeholders)
-    stay resident on the device after their first upload."""
+    results) and ``.consume`` (the host mirrors), with ``<path>`` as the
+    caller names it (``path``), else ``tick`` for ``retrigger`` and
+    ``event`` otherwise; the returned tick counts the uploads (``h2d``)
+    and reads (``d2h``).  The row arrays reach the jit as host arrays;
+    the constants (prewarm tables, K, placeholders) stay resident on the
+    device after their first upload."""
     if qs.n_shards != 1:
         raise ValueError("refresh_ranks_delta serves 1-shard arenas; "
                          "mesh-sharded stores go through refresh_ranks_mesh")
-    path = "tick" if retrigger else "event"
+    path = path or ("tick" if retrigger else "event")
     io = _Crossings()
     with_pw = prewarm_table is not None
     D = len(walked)

@@ -350,7 +350,8 @@ class HermesScheduler:
         qs.clear_dirty(slots)
 
     def _priorities_delta(self, now: float,
-                          app_ids: Optional[List[str]] = None
+                          app_ids: Optional[List[str]] = None, *,
+                          caller: Optional[str] = None
                           ) -> Dict[str, float]:
         """The delta tick: drain the dirty set, walk ONLY those slots (full
         re-walk past the dirty-fraction threshold), re-rank from the
@@ -367,7 +368,12 @@ class HermesScheduler:
         On the single arena the host work before and after
         :func:`refresh_ranks_delta` runs in its ``hermes.<path>.prepare``
         and ``.consume`` spans, and the dispatch is counted in
-        ``refresh_stats``."""
+        ``refresh_stats`` under ``<path>_dispatches``; the policy's use of
+        the store's columns runs in ``hermes.key``, inside ``.consume``.
+        ``<path>`` is ``caller`` (``"tick"`` for a bucket tick, ``"event"``
+        for an event batch) where the host names it, else ``tick`` for a
+        full call and ``event`` for a subset one; it names the dispatch
+        and changes nothing of what it does."""
         qs = self._ensure_qstate()
         if len(qs) == 0:
             return {}
@@ -375,7 +381,7 @@ class HermesScheduler:
         if self.refresh_mesh is not None:
             live, walked, tab = self._take_walk(qs, app_ids)
             return self._priorities_mesh(qs, live, walked, now, tab, full)
-        path = "tick" if full else "event"
+        path = caller or ("tick" if full else "event")
         with span(path + ".prepare"):
             live, walked, tab = self._take_walk(qs, app_ids)
         tick = refresh_ranks_delta(
@@ -386,7 +392,7 @@ class HermesScheduler:
             compact_shrink=self.compact_shrink,
             prewarm_table=tab, prewarm_k=self.K, retrigger=full,
             with_triage=self._with_triage, posterior=self.posterior,
-            rank_in_kernel=self.rank_in_kernel)
+            rank_in_kernel=self.rank_in_kernel, path=path)
         with span(path + ".consume"):
             self.fused_spill += tick.spill
             stats = self.refresh_stats
@@ -408,7 +414,8 @@ class HermesScheduler:
                 qs.bump_refresh(walked)
                 for s in walked:
                     self.apps[qs.ids[int(s)]].refreshes += 1
-            return self._ranks_from_store(qs, live, tick.ranks, now)
+            with span("key"):
+                return self._ranks_from_store(qs, live, tick.ranks, now)
 
     def _take_walk(self, qs, app_ids: Optional[List[str]]):
         """What a delta tick walks: ``(live apps it ranks, slots to walk,
@@ -844,14 +851,18 @@ class HermesScheduler:
 
     # ------------------------------------------------------------ decisions
     def priorities(self, now: float,
-                   app_ids: Optional[List[str]] = None) -> Dict[str, float]:
+                   app_ids: Optional[List[str]] = None, *,
+                   caller: Optional[str] = None) -> Dict[str, float]:
         """Rank live applications (lower = run first).  Called once per
         bucket period — the Fig. 15 hot path.  ``app_ids`` restricts the
         ranking to a subset (ranks are per-app independent, so hosts can
         re-rank just the applications an event touched between full ticks).
+        ``caller`` (``"tick"`` or ``"event"``) names the single-arena
+        delta dispatch's spans and counter by the host's call that made
+        it; it changes no rank.
         """
         if self._delta_active():
-            return self._priorities_delta(now, app_ids)
+            return self._priorities_delta(now, app_ids, caller=caller)
         if app_ids is None:
             live = list(self._live.values())
         else:
@@ -885,7 +896,8 @@ class HermesScheduler:
         return {a.app_id: float(r) for a, r in zip(live, ranks)}
 
     def priorities_arrays(self, now: float,
-                          app_ids: Optional[List[str]] = None
+                          app_ids: Optional[List[str]] = None, *,
+                          caller: Optional[str] = None
                           ) -> Tuple[List[str], np.ndarray]:
         """Array-facing twin of :meth:`priorities`: ``(app_ids, ranks)``
         with the ranks as one float64 vector instead of a dict of boxed
@@ -912,7 +924,7 @@ class HermesScheduler:
                 return [], np.zeros(0)
             return ([a.app_id for a in live],
                     np.asarray(self.policy.ranks(live, now), np.float64))
-        d = self.priorities(now, app_ids)
+        d = self.priorities(now, app_ids, caller=caller)
         return list(d), np.fromiter(d.values(), np.float64, count=len(d))
 
     def on_arrivals(self, items: List[tuple], now: float) -> None:

@@ -997,6 +997,9 @@ class ClusterSim:
         full re-rank on every event."""
         t0 = _time.perf_counter()
         policy = self.sched.policy
+        # the scheduler names the dispatch by this call, not by what it
+        # re-ranks: an event batch's full re-rank is an event dispatch
+        caller = "tick" if app_ids is None else "event"
         subset = app_ids is not None and \
             getattr(policy, "independent_ranks", True)
         task_level = getattr(policy, "task_level", False)
@@ -1014,7 +1017,8 @@ class ClusterSim:
                 sel = None
             ids = []
             if sel is None or sel:
-                ids, ranks = self.sched.priorities_arrays(self.now, sel)
+                ids, ranks = self.sched.priorities_arrays(self.now, sel,
+                                                          caller=caller)
             with span("rekey"):
                 if ids:
                     idx = np.fromiter((self._app_ai[i] for i in ids),
@@ -1028,10 +1032,10 @@ class ClusterSim:
                         wq.rebuild(self._rank_arr)
         else:
             if subset:
-                self._ranks.update(self.sched.priorities(self.now,
-                                                         app_ids=app_ids))
+                self._ranks.update(self.sched.priorities(
+                    self.now, app_ids=app_ids, caller=caller))
             else:
-                self._ranks = self.sched.priorities(self.now)
+                self._ranks = self.sched.priorities(self.now, caller=caller)
                 for wq in self.waiting.values():
                     wq.rebuild(self._task_rank)
         self.policy_time += _time.perf_counter() - t0

@@ -14,8 +14,8 @@ Pinned here:
   run under JAX's guard that refuses every implicit transfer;
 * the counts per dispatch, derived from the code path by path;
 * a simulator run under the profiler writes the program's spans
-  (``hermes.<path>.{prepare,wait,consume}``, ``hermes.rekey``), with one
-  ``wait`` span per counted dispatch.
+  (``hermes.<path>.{prepare,wait,consume}``, ``hermes.key``,
+  ``hermes.rekey``), with one ``wait`` span per counted dispatch.
 """
 import collections
 import glob
@@ -177,4 +177,4 @@ def test_sim_run_writes_the_spans(kb, tmp_path):
     assert n["hermes.rekey"] >= stats["tick_dispatches"]
     assert set(n) == {f"hermes.{p}.{ph}" for p in ("event", "tick")
                       for ph in ("prepare", "wait", "consume")} | \
-        {"hermes.rekey"}
+        {"hermes.rekey", "hermes.key"}
