@@ -647,12 +647,14 @@ def refresh_ranks_fused(packed: PackedKB, qs: QueueState, base_key, seed,
 class DeltaTick:
     """Results of one delta tick: arena-wide ranks plus the set of slots
     whose estimates were actually re-walked, and the dispatch's
-    host->device uploads and device->host reads."""
+    host->device uploads and device->host reads, with the reads whose host
+    copy started at the enqueue."""
     ranks: np.ndarray          # (capacity,) — index by slot id; holes garbage
     spill: int
     walked: np.ndarray         # slot ids re-walked (and scattered) this tick
     h2d: int = 0
     d2h: int = 0
+    d2h_async: int = 0
 
 
 # Device copies of the dispatch constants, most recently used last: key ->
@@ -669,10 +671,11 @@ class _Crossings:
     array goes through ``put``, which hands it back unchanged for the jit
     to upload on its C++ argument path; a constant comes from ``resident``
     (``zeros``, ``scalar``), which uploads it only when the process-wide
-    cache misses; every read-back goes through ``get``."""
+    cache misses; the results' host copies start in ``start`` and are
+    read back together in ``get``."""
 
     def __init__(self):
-        self.h2d = self.d2h = 0
+        self.h2d = self.d2h = self.d2h_async = 0
 
     def put(self, x):
         self.h2d += 1
@@ -701,9 +704,20 @@ class _Crossings:
         return self.resident(("scalar", float(value)),
                              lambda: (np.float32(value),))[0]
 
-    def get(self, x):
-        self.d2h += 1
-        return np.asarray(x)
+    def start(self, out):
+        """Start every device result's copy to the host now, so the copies
+        queue behind the program that makes them instead of each waiting
+        for the read before it; counted per array started."""
+        for v in out.values():
+            if hasattr(v, "copy_to_host_async"):
+                v.copy_to_host_async()
+                self.d2h_async += 1
+
+    def get(self, out):
+        """Every result of ``out`` on the host, in one read; counted per
+        array."""
+        self.d2h += len(out)
+        return jax.device_get(out)
 
 
 def _retrigger_rows(qs: QueueState, walked: np.ndarray, io: _Crossings):
@@ -747,13 +761,14 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
     bump ``walked`` after consuming.
 
     The dispatch runs in three host spans, ``hermes.<path>.prepare`` (row
-    gather and uploads, up to the enqueue), ``.wait`` (every read of the
-    results) and ``.consume`` (the host mirrors), with ``<path>`` as the
-    caller names it (``path``), else ``tick`` for ``retrigger`` and
-    ``event`` otherwise; the returned tick counts the uploads (``h2d``)
-    and reads (``d2h``).  The row arrays reach the jit as host arrays;
-    the constants (prewarm tables, K, placeholders) stay resident on the
-    device after their first upload."""
+    gather and uploads, the enqueue, and the start of every result's copy
+    to the host), ``.wait`` (one read of all the results) and ``.consume``
+    (the host mirrors), with ``<path>`` as the caller names it (``path``),
+    else ``tick`` for ``retrigger`` and ``event`` otherwise; the returned
+    tick counts the uploads (``h2d``), the reads (``d2h``) and the reads
+    whose copy started at the enqueue (``d2h_async``).  The row arrays
+    reach the jit as host arrays; the constants (prewarm tables, K,
+    placeholders) stay resident on the device after their first upload."""
     if qs.n_shards != 1:
         raise ValueError("refresh_ranks_delta serves 1-shard arenas; "
                          "mesh-sharded stores go through refresh_ranks_mesh")
@@ -838,8 +853,9 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
                 qs.a_hist, qs.a_lo, qs.a_span, qs.a_reach = \
                     a_hist, a_lo, a_span, a_reach
                 out.update(trigger=trigger, reach=reach)
+        io.start(out)
     with span(path + ".wait"):
-        got = {k: io.get(v) for k, v in out.items()}
+        got = io.get(out)
     with span(path + ".consume"):
         if D and with_pw:
             qs.a_att[walked] = qs.attained[walked]
@@ -857,4 +873,4 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
                 qs.trig[walked] = got["trigger"][:D]
                 qs.reach[walked] = got["reach"][:D]
         return DeltaTick(got["ranks"], int(got["spill"]) if D else 0,
-                         walked, io.h2d, io.d2h)
+                         walked, io.h2d, io.d2h, io.d2h_async)

@@ -137,7 +137,7 @@ class HermesScheduler:
         self.fused_spill = 0              # walkers truncated by compaction
         # single-arena delta dispatches and their host<->device transfers
         self.refresh_stats = {"event_dispatches": 0, "tick_dispatches": 0,
-                              "h2d": 0, "d2h": 0}
+                              "h2d": 0, "d2h": 0, "d2h_async": 0}
         self.warmup_table = warmup_table  # per-key warm-up cost overrides
         self._prewarm_tab = None          # (kb token, PrewarmTable) cache
         self.prewarm_plan: Optional[PrewarmPlan] = None   # last fused plan
@@ -399,6 +399,7 @@ class HermesScheduler:
             stats[path + "_dispatches"] += 1
             stats["h2d"] += tick.h2d
             stats["d2h"] += tick.d2h
+            stats["d2h_async"] += tick.d2h_async
             if full:
                 qs.take_rank_dirty()     # arena-wide re-rank covered everyone
             if tab is not None:

@@ -168,8 +168,10 @@ def test_naming_changes_no_bit(kb, monkeypatch, policy):
     assert res_a.acts == res_b.acts and res_a.dsr == res_b.dsr
     sa, sb = sim_a.sched.refresh_stats, sim_b.sched.refresh_stats
     # uploads differ by the resident constants, which only the first run
-    # in the process sends; reads are per dispatch
+    # in the process sends; reads are per dispatch, each one's copy
+    # started at the enqueue
     assert sa["d2h"] == sb["d2h"]
+    assert sa["d2h_async"] == sb["d2h_async"] == sa["d2h"]
     total = len(a["dispatches"])
     assert sa["event_dispatches"] + sa["tick_dispatches"] == total
     assert sb["event_dispatches"] + sb["tick_dispatches"] == total

@@ -3,8 +3,9 @@
 Every per-dispatch host->device upload of a single-arena delta dispatch
 goes through the counting helper ``put``, which hands the host array to
 the jit; the constants (prewarm tables, K, placeholders) stay resident
-on the device after one counted upload; every device->host read goes
-through ``get``.  The scheduler sums the counts into ``refresh_stats``.
+on the device after one counted upload; every device->host read starts
+its copy at the enqueue (``start``) and is read back in ``get``.  The
+scheduler sums the counts into ``refresh_stats``.
 Pinned here:
 
 * no upload bypasses the helpers and no constant crosses again: under a
@@ -85,8 +86,9 @@ def _dispatch(s, kind):
 def test_dispatch_counts_its_crossings(kb, kind):
     s = _sched(kb)
     key, h2d, d2h = CROSSINGS[kind]
+    # every read's host copy starts at the enqueue
     want = {"event_dispatches": 0, "tick_dispatches": 0,
-            "h2d": h2d, "d2h": d2h}
+            "h2d": h2d, "d2h": d2h, "d2h_async": d2h}
     want[key] = 1
     for i in range(3):
         got = _dispatch(s, kind)
