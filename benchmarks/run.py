@@ -27,19 +27,18 @@ def main(argv=None) -> None:
                          "seconds (import/API drift canary, not a benchmark)")
     ap.add_argument("--only", default="",
                     help="comma list: fig9,fig11,fig12,fig13,fig14,fig15,"
-                         "refresh,roofline,prewarm")
+                         "roofline,prewarm")
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
 
     only = set(args.only.split(",")) if args.only else None
     csv = Csv()
     from benchmarks import (fig9_act, fig11_ddl, fig12_ablation, fig13_cache,
-                            fig14_prewarm, fig15_overhead, prewarm,
-                            refresh_tick, roofline)
+                            fig14_prewarm, fig15_overhead, prewarm, roofline)
     table = {"fig9": fig9_act, "fig11": fig11_ddl, "fig12": fig12_ablation,
              "fig13": fig13_cache, "fig14": fig14_prewarm,
-             "fig15": fig15_overhead, "refresh": refresh_tick,
-             "roofline": roofline, "prewarm": prewarm}
+             "fig15": fig15_overhead, "roofline": roofline,
+             "prewarm": prewarm}
     if only and (unknown := only - set(table)):
         # a typo'd section must not silently no-op (CI would stay green)
         ap.error(f"unknown --only section(s): {sorted(unknown)}; "

@@ -2,10 +2,10 @@
 """On-chip smoke run of the Hermes refresh path.
 
 One chip (the default): build the knowledge base and an overloaded
-open-arrival trace from ``--seed`` (gamma arrivals, cv 2.5, 16 tenants — the
-``benchmarks/sim_scale.py`` shape), then drive ``ClusterSim`` with
-``SimConfig(policy="gittins")`` — the default ``RefreshConfig``, so every
-refresh tick runs the fused Pallas program on the device — until the slot
+open-arrival trace from ``--seed`` (gamma arrivals, cv 2.5, 16 tenants, ten
+times the load of 1024 service slots over 900 s), then drive ``ClusterSim``
+with ``SimConfig(policy="gittins")`` — the default ``RefreshConfig``, so
+every refresh tick runs the Pallas program on the device — until the slot
 arena holds ``--backlog`` live applications and ``--ticks`` refresh ticks
 have run at that backlog.  The last arena is then ranked twice on the chip,
 by the kernel and by the oracle composition (jnp twin →
@@ -39,7 +39,7 @@ ROOT = Path(__file__).resolve().parent
 SRC = ROOT / "src"
 
 SIM_SCALE_TRACE = dict(duration_s=900.0, target_load=10.0,
-                       n_service_slots=1024)   # benchmarks/sim_scale.py FULL
+                       n_service_slots=1024)
 FALLBACK_WARNING = "pdgraph_walk: requested impl='pallas' fell back"
 MARGIN = 2048              # applications in the trace beyond the backlog
 MAX_EVENTS = 2_000_000     # hard cap on drained simulator events

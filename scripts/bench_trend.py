@@ -1,9 +1,10 @@
-"""Benchmark trend gate: fail CI when a refresh-tick arm regresses.
+"""Benchmark trend gate: fail CI when a seeded benchmark record regresses.
 
-Compares a fresh ``BENCH_refresh_tick.json`` (written by every
-``benchmarks/refresh_tick.py`` invocation, including ``--smoke``) against a
-committed baseline record and exits non-zero when any arm present in BOTH
-files regressed by more than ``--max-regress-pct`` in ms/tick.
+Compares a fresh benchmark record (``BENCH_overload.json``,
+``BENCH_drift.json``: each run of their benchmark writes one, including
+``--smoke``) against a committed baseline record and exits non-zero when
+any row present in BOTH files regressed by more than ``--max-regress-pct``
+in the gated field.
 
 Honesty guards (cross-machine timing comparisons lie — see
 docs/BENCHMARKS.md):
@@ -30,11 +31,13 @@ docs/BENCHMARKS.md):
   fails the gate even though no shared row regressed.
 
 Usage:
-  python scripts/bench_trend.py BENCH_refresh_tick.json \
-      --baseline benchmarks/baselines/BENCH_refresh_tick.smoke.json
+  python scripts/bench_trend.py BENCH_overload.json \
+      --baseline benchmarks/baselines/BENCH_overload.smoke.json \
+      --field goodput_per_s --direction max --min-ms 0 --force
   # refresh the baseline (run the benchmark a few times, folding each in):
-  python scripts/bench_trend.py BENCH_refresh_tick.json --update \
-      --baseline benchmarks/baselines/BENCH_refresh_tick.smoke.json
+  python scripts/bench_trend.py BENCH_overload.json --update \
+      --baseline benchmarks/baselines/BENCH_overload.smoke.json \
+      --field goodput_per_s --direction max --min-ms 0 --force
 
 Stdlib-only (runs in the CI canary step before any install caching).
 """
@@ -67,7 +70,7 @@ def load_rows(path: str, field: str) -> dict:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("fresh", help="freshly written BENCH_refresh_tick.json")
+    ap.add_argument("fresh", help="freshly written benchmark record")
     ap.add_argument("--baseline", required=True,
                     help="committed baseline record to compare against")
     ap.add_argument("--max-regress-pct", type=float, default=25.0,

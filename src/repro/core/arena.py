@@ -45,7 +45,7 @@ Result rows:
 * ``sup`` / ``opt`` / ``mean`` — (cap,) triage scalars, host mirrors for
   the composite policies.
 * ``trig`` / ``reach`` — (cap, B) prewarm rows, host mirrors the batched
-  planner reads (``plan_from_store``).
+  planner reads (``PrewarmPlan.from_store``).
 
 **Repack**: the arena never shrinks within an epoch (grow-only, holes
 masked).  ``repack()`` rebuilds it at the smallest fitting capacity —
@@ -156,10 +156,6 @@ class QueueState:
         for d in self._dirty:
             out |= d
         return out
-
-    @property
-    def dirty_count(self) -> int:
-        return sum(len(d) for d in self._dirty)
 
     def _add_dirty(self, slot: int) -> None:
         self._dirty[slot % self.n_shards].add(slot)
@@ -367,38 +363,6 @@ class QueueState:
         self.ov_counts[slots] = 0
         return slots
 
-    def retire_many(self, app_ids: Sequence[str]) -> np.ndarray:
-        """Release a batch of applications' slots in one call (same
-        per-slot semantics as :meth:`retire`; occupancy cleared as one
-        scatter).  Unknown / already-retired ids are skipped.  Returns the
-        freed slot ids."""
-        freed: List[int] = []
-        for app_id in app_ids:
-            i = self.slot.pop(app_id, None)
-            if i is None:
-                continue
-            if self.ov_counts[i].any():
-                self.override_apps -= 1
-            self.ids[i] = None
-            freed.append(i)
-            self._dirty[i % self.n_shards].discard(i)
-            self.rank_dirty.discard(i)
-            self._frees[i % self.n_shards].append(i)
-        out = np.asarray(freed, np.int64)
-        if len(out):
-            self._occ[out] = False
-            self.ov_counts[out] = 0
-            self.live -= len(out)
-        return out
-
-    def mark_dirty_many(self, app_ids: Sequence[str]) -> None:
-        """Mark a batch of applications' slots for the next delta walk in
-        one call (unknown ids skipped, like :meth:`mark_dirty`)."""
-        for app_id in app_ids:
-            i = self.slot.get(app_id)
-            if i is not None:
-                self._dirty[i % self.n_shards].add(i)
-
     def retire(self, app_id: str) -> None:
         """Release an application's slot back to its shard's free-list.  The
         row's values stay in place (stale-but-in-bounds — dispatches mask
@@ -445,13 +409,6 @@ class QueueState:
         self.ov_samples[i, unit_idx, :len(arr)] = arr
         self.ov_counts[i, unit_idx] = len(arr)
         self._add_dirty(i)
-
-    def get_deadline(self, slot: int) -> Optional[float]:
-        """Slot's deadline row (None when the app has no deadline) — the
-        store is the view-refresh source for per-slot scalars in delta
-        mode."""
-        d = self.deadline[slot]
-        return None if np.isinf(d) else float(d)
 
     def set_stretch(self, app_id: str, stretch: float) -> None:
         self.stretch[self.slot[app_id]] = stretch

@@ -407,8 +407,8 @@ def _mc_walk_batch(samples, counts, cum_trans,          # (G,U,S),(G,U),(G,U,U+1
                    track_arrivals: bool = False,
                    po_cum=None, po_scale=None) -> jnp.ndarray:
     """One dispatch for the whole queue: vmap of `_walk_core` with per-app
-    graph gather and per-app fold_in keys (identical bits to the looped
-    per-app path, which derives the same fold_in chain).  With
+    graph gather and per-app fold_in keys (identical bits to the
+    single-app walk, which derives the same fold_in chain).  With
     ``track_arrivals`` returns ``(totals (A,W), arrivals (A,W,U))``.
 
     ``po_cum (A, U, U+1)`` / ``po_scale (A, U)`` (posterior-blended walk

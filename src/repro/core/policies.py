@@ -15,17 +15,17 @@ Every policy maps application states to scalar ranks — lower rank runs first.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from repro.core.gittins import (gittins_rank_hist_np, to_histogram,
                                 to_histogram_batch)
 
-# The fused pipeline computes the composite policies' triage quantiles on
-# device at THESE fixed probabilities (repro.core.refresh._triage_stats);
-# a policy instance re-tuned away from them loses fused eligibility and
+# The device pipeline computes the composite policies' triage quantiles at
+# THESE fixed probabilities (repro.core.refresh_pipeline._triage_stats); a
+# policy instance re-tuned away from them loses device eligibility and
 # falls back to the host-quantile path (see Policy.fused_capable).
 SUP_Q = 0.9           # worst-case demand quantile (eq. 2 "sup X")
 HOPELESS_Q = 0.1      # optimistic quantile for the hopeless-class gate
@@ -33,27 +33,17 @@ HOPELESS_Q = 0.1      # optimistic quantile for the hopeless-class gate
 
 @dataclass
 class AppView:
-    """What a policy may see about one application.
-
-    In the scheduler's fused refresh mode ``total_samples`` is None — the
-    sample matrix never reaches the host; the view instead carries the
-    device-computed histogram rows (``hist``) and, until invalidated by
-    further progress, the device-computed Gittins rank (``fused_rank``).
-    For the composite (deadline) policies it additionally carries the
-    device-computed triage scalars: the SUP_Q/HOPELESS_Q quantiles and the
-    mean of the TOTAL demand distribution."""
+    """What a policy may see about one application on the composed host
+    path (the device path ranks off the slot store's columns instead, see
+    :meth:`Policy.ranks_columns`)."""
     app_id: str
     tenant: str
     arrival: float
     attained: float                      # service seconds received so far
-    total_samples: Optional[np.ndarray]  # est. TOTAL demand distribution
+    total_samples: np.ndarray            # est. TOTAL demand distribution
     deadline: Optional[float] = None
     oracle_remaining: Optional[float] = None
     hist: Optional[tuple] = None         # cached (probs, edges)
-    fused_rank: Optional[float] = None   # device-computed rank (fused mode)
-    demand_sup: Optional[float] = None   # device P_{SUP_Q}(total demand)
-    demand_opt: Optional[float] = None   # device P_{HOPELESS_Q}(total demand)
-    demand_mean: Optional[float] = None  # device mean(total demand)
 
 
 class Policy:
@@ -64,9 +54,10 @@ class Policy:
     # other apps, shared counters, or wall time) — hosts may then re-rank
     # just the apps an event touched between full bucket-tick refreshes
     independent_ranks = True
-    # True when this policy can consume the fused dispatch's device-computed
-    # outputs (ranks / hists / triage scalars) instead of raw sample arrays;
-    # the scheduler only engages the fused pipeline for such policies
+    # True when this policy can consume the device dispatch's outputs (ranks
+    # / triage scalars) instead of raw sample arrays; the scheduler only
+    # engages the device pipeline for such policies: the plain Gittins
+    # policy, and the columns_capable ones below
     fused_capable = False
     # True when ranks read only per-app scheduler bookkeeping (arrival /
     # tenant / deadline) and never the demand estimate: the scheduler skips
@@ -104,21 +95,15 @@ class GittinsPolicy(Policy):
     name = "gittins"
     fused_capable = True
 
-    def __init__(self, n_buckets: int = 10, vectorized: bool = True):
+    def __init__(self, n_buckets: int = 10):
         self.n_buckets = n_buckets
-        self.vectorized = vectorized   # False = seed-style per-app bucketize
 
     def ranks(self, apps: List[AppView], now: float) -> np.ndarray:
         if not apps:
             return np.zeros(0)
-        # fused path: the scheduler already computed every rank on device in
-        # the fused refresh dispatch — accept them directly, no host
-        # bucketize / rank dispatch at all
-        if all(a.fused_rank is not None for a in apps):
-            return np.asarray([a.fused_rank for a in apps], np.float32)
         stale = [a for a in apps
                  if a.hist is None or a.hist[0].shape[0] != self.n_buckets]
-        if self.vectorized and len(stale) > 1 and \
+        if len(stale) > 1 and \
                 len({len(a.total_samples) for a in stale}) == 1:
             # whole-queue bucketization in one vectorized pass
             P, E = to_histogram_batch(
@@ -194,18 +179,9 @@ class EDFPolicy(Policy):
 
 def _demand_stats(apps: List[AppView], sup_q: float, hopeless_q: float
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """(P_sup, P_hopeless, mean) of every app's demand samples — read off
-    the fused dispatch's device-computed view scalars when present (no
-    per-app host quantile pulls on the tick path), one vectorized pass when
-    the queue's sample arrays share a length (the batched-refresh common
-    case), per-app otherwise."""
-    if all(a.total_samples is None for a in apps):
-        # fused refresh: the sample matrix never reached the host; the
-        # dispatch computed these at (SUP_Q, HOPELESS_Q) — the scheduler
-        # guarantees the policy's quantiles match before engaging fused mode
-        return (np.asarray([a.demand_sup for a in apps], np.float64),
-                np.asarray([a.demand_opt for a in apps], np.float64),
-                np.asarray([a.demand_mean for a in apps], np.float64))
+    """(P_sup, P_hopeless, mean) of every app's demand samples — one
+    vectorized pass when the queue's sample arrays share a length (the
+    batched-refresh common case), per-app otherwise."""
     lens = {len(a.total_samples) for a in apps}
     if len(apps) > 1 and len(lens) == 1:
         M = np.stack([a.total_samples for a in apps])
@@ -313,14 +289,6 @@ class HermesDDLPolicy(Policy):
     def fused_capable(self) -> bool:
         return (self.sup_q, self.hopeless_q) == (SUP_Q, HOPELESS_Q)
 
-    @property
-    def vectorized(self) -> bool:
-        return self.gittins.vectorized
-
-    @vectorized.setter
-    def vectorized(self, value: bool) -> None:
-        self.gittins.vectorized = value
-
     def ranks(self, apps, now):
         g = self.gittins.ranks(apps, now)
         g = np.minimum(g, self.cls_span * 0.99)
@@ -346,12 +314,12 @@ class HermesDDLPolicy(Policy):
     def ranks_columns(self, now, *, g, sup, opt, attained, deadline,
                       mean=None):
         """Vectorized :meth:`ranks` over store columns.  Bit-identical to
-        the per-app loop on fused views: the loop's ``cls * cls_span + gr``
-        adds a weak Python float to a float32 device rank — NEP-50 performs
-        that add in float32 — so this path clips and accumulates in float32
-        too.  ``deadline=np.inf`` rows land in the safe class (inf slack),
-        whose ``1 * cls_span + g`` equals the loop's explicit no-deadline
-        branch."""
+        the per-app loop given the same float32 Gittins ranks: the loop's
+        ``cls * cls_span + gr`` adds a weak Python float to a float32 rank
+        — NEP-50 performs that add in float32 — so this path clips and
+        accumulates in float32 too.  ``deadline=np.inf`` rows land in the
+        safe class (inf slack), whose ``1 * cls_span + g`` equals the
+        loop's explicit no-deadline branch."""
         g32 = np.minimum(np.asarray(g, np.float32),
                          np.float32(self.cls_span * 0.99))
         sup = np.asarray(sup, np.float64)

@@ -16,25 +16,22 @@ backend with branch probability p_s and warm-up duration t_p, and the
 prewarm decisions is a constructor on it, and merging is a method:
 
 * ``PrewarmPlan.from_store(store, slots, now, table)`` — batched device
-  plan (fused refresh mode): the fused refresh walk records per-walker
-  first-arrival times into every unit; the pipeline reduces them on device
-  into per-(app, backend-class) arrival histograms and trigger quantiles,
-  generalizing the one-hop branch probability p_s to the full reach
-  probability over ALL downstream units.  ``PrewarmTable`` packs the
-  unit -> warmable-backend-class mapping and per-class warm-up durations
-  into device constants; this constructor reads the store's persisted
-  trigger rows — no per-application host loop anywhere on the tick path.
+  plan (the ``fused_delta`` path): the device refresh walk records
+  per-walker first-arrival times into every unit; the pipeline reduces
+  them on device into per-(app, backend-class) arrival histograms and
+  trigger quantiles, generalizing the one-hop branch probability p_s to
+  the full reach probability over ALL downstream units.
+  ``PrewarmTable`` packs the unit -> warmable-backend-class mapping and
+  per-class warm-up durations into device constants; this constructor
+  reads the store's persisted trigger rows — no per-application host loop
+  anywhere on the tick path.
 * ``PrewarmPlan.from_triggers(app_ids, trigger, p_reach, now, table)`` —
   the same reduction from an explicit ``(A, B)`` device trigger matrix.
 * ``PrewarmPlan.one_hop(graph, app_id, ...)`` — the original per-app
-  immediate-successor planner, retained for the looped/composed refresh
-  modes and as the closed-form oracle the batched plan is tested against.
+  immediate-successor planner, retained for the composed refresh path and
+  as the closed-form oracle the batched plan is tested against.
 * ``plan.merge(other, is_live)`` — dedup two plans on (app, class), newest
   trigger winning, dead apps pruned.
-
-The former module-level entry points (``plan_from_store``,
-``plan_from_triggers``, ``plan_prewarms``, ``merge_plans``) remain as
-deprecated wrappers for one release.
 """
 from __future__ import annotations
 
@@ -86,25 +83,8 @@ class PrewarmSignal:
     p_s: float
 
 
-def plan_prewarms(graph: PDGraph, app_id: str, current_unit: str,
-                  unit_start: float, now: float, K: float,
-                  warmup_time_of, is_warm, t_in: float, t_out: float
-                  ) -> List[PrewarmSignal]:
-    """Deprecated: use :meth:`PrewarmPlan.one_hop` (and its ``signals()``)."""
-    _deprecated("plan_prewarms", "PrewarmPlan.one_hop(...).signals()")
-    return list(PrewarmPlan.one_hop(graph, app_id, current_unit, unit_start,
-                                    now, K, warmup_time_of, is_warm,
-                                    t_in, t_out).signals())
-
-
-def _deprecated(old: str, new: str) -> None:
-    import warnings
-    warnings.warn(f"repro.core.prewarm.{old} is deprecated; use {new}",
-                  DeprecationWarning, stacklevel=3)
-
-
 # ---------------------------------------------------------------------------
-# Batched device-resident planning (rides the fused refresh dispatch)
+# Batched device-resident planning (rides the device refresh dispatch)
 # ---------------------------------------------------------------------------
 
 @dataclass(frozen=True)
@@ -237,8 +217,8 @@ class PrewarmPlan:
         """The legacy host planner: triggers for the cold backends of
         ``current_unit``'s *immediate* successors only, from the closed-form
         §3.4 quantile (``warmup_time_of(resource_key) -> seconds``;
-        ``is_warm(key) -> bool``).  Retained for the looped/composed refresh
-        modes and as the oracle the batched plan is tested against."""
+        ``is_warm(key) -> bool``).  Retained for the composed refresh
+        path and as the oracle the batched plan is tested against."""
         cur = graph.units[current_unit]
         dur = cur.service_samples(t_in, t_out)
         ids: List[str] = []
@@ -291,24 +271,3 @@ class PrewarmPlan:
             p_reach=np.asarray([merged[k][2] for k in keys], np.float32),
             units=[merged[k][3] for k in keys])
 
-
-def plan_from_store(store, slots: np.ndarray, now: float,
-                    table: PrewarmTable) -> PrewarmPlan:
-    """Deprecated: use :meth:`PrewarmPlan.from_store`."""
-    _deprecated("plan_from_store", "PrewarmPlan.from_store")
-    return PrewarmPlan.from_store(store, slots, now, table)
-
-
-def plan_from_triggers(app_ids: Sequence[str], trigger: np.ndarray,
-                       p_reach: np.ndarray, now: float,
-                       table: PrewarmTable) -> PrewarmPlan:
-    """Deprecated: use :meth:`PrewarmPlan.from_triggers`."""
-    _deprecated("plan_from_triggers", "PrewarmPlan.from_triggers")
-    return PrewarmPlan.from_triggers(app_ids, trigger, p_reach, now, table)
-
-
-def merge_plans(prev: PrewarmPlan, plan: PrewarmPlan,
-                is_live) -> PrewarmPlan:
-    """Deprecated: use :meth:`PrewarmPlan.merge`."""
-    _deprecated("merge_plans", "PrewarmPlan.merge")
-    return prev.merge(plan, is_live)

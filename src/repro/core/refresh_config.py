@@ -1,14 +1,5 @@
 """RefreshConfig: the one validated construction surface for the refresh
-backbone.
-
-Before this module, the knobs that select and tune the priority-refresh
-pipeline — ``refresh_mode``/``mode``, ``walker``, ``mesh_shards``,
-``delta_full_threshold``, ``queue_delay_correction`` — were duplicated as
-loose keyword arguments on both ``HermesScheduler.__init__`` and
-``SimConfig``, each with its own copy of the validation rules (and the
-``mesh_shards``-requires-``fused_delta`` check lived only in the
-scheduler).  ``RefreshConfig`` consolidates them: build one, pass it to
-either entry point::
+backbone.  Build one and pass it to either entry point::
 
     from repro.core import RefreshConfig
     from repro.core.scheduler import HermesScheduler
@@ -18,9 +9,7 @@ either entry point::
     sched = HermesScheduler(kb, policy="gittins", refresh=rc)
     cfg = SimConfig(policy="gittins", refresh=rc)
 
-The legacy kwargs were deprecation shims for one release (PR 6) and are
-now retired: passing any of them raises :class:`TypeError` with a
-migration pointer.  Every validation rule lives in exactly one place,
+Every validation rule lives in exactly one place,
 ``RefreshConfig.__post_init__``.
 """
 from __future__ import annotations
@@ -28,12 +17,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
-MODES = ("looped", "composed", "fused", "fused_delta")
+MODES = ("composed", "fused_delta")
 WALKERS = ("pallas", "threefry")
-
-# sentinel distinguishing "caller never passed this kwarg" from an explicit
-# None/default (the deprecation shims must only warn on explicit use)
-_UNSET = object()
 
 
 @dataclass(frozen=True)
@@ -41,12 +26,13 @@ class RefreshConfig:
     """Validated refresh-backbone configuration (see module docstring).
 
     mode
-        ``looped`` (seed per-app walk), ``composed`` (PR-1 batched walk),
-        ``fused`` (one device dispatch per tick), ``fused_delta`` (the
-        default: dirty-set delta refresh over the persistent slot arena).
+        ``fused_delta`` (the default: the device path, a dirty-set delta
+        refresh over the persistent slot arena) or ``composed`` (the host
+        path: one batched MC walk per refresh, the policies with no device
+        program, and the host oracle the device path is tested against).
     walker
-        Fused-mode MC backend: ``pallas`` (counter-RNG kernel package,
-        fastest) or ``threefry`` (bit-identical streams to composed/looped).
+        Device-path MC backend: ``pallas`` (counter-RNG kernel package,
+        fastest) or ``threefry`` (bit-identical streams to composed).
     mesh_shards
         Partition the slot arena across this many mesh devices (power of
         two; requires ``mode="fused_delta"``).  ``None`` keeps the
@@ -91,8 +77,6 @@ class RefreshConfig:
             raise ValueError(f"unknown fused walker {self.walker!r}; "
                              f"known: {WALKERS}")
         if self.mesh_shards is not None:
-            # the one rule that used to live only in HermesScheduler — now
-            # both entry points (and any direct construction) share it
             if self.mode != "fused_delta":
                 raise ValueError("mesh_shards requires mode='fused_delta' "
                                  f"(got mode={self.mode!r})")
@@ -117,33 +101,3 @@ class RefreshConfig:
         if not 0.0 <= self.delta_full_threshold <= 1.0:
             raise ValueError("delta_full_threshold must be in [0, 1], "
                              f"got {self.delta_full_threshold}")
-
-
-def resolve_refresh_config(refresh: Optional[RefreshConfig], *,
-                           owner: str,
-                           mode=_UNSET, walker=_UNSET, mesh_shards=_UNSET,
-                           delta_full_threshold=_UNSET,
-                           queue_delay_correction=_UNSET,
-                           stacklevel: int = 3) -> RefreshConfig:
-    """Resolve the refresh configuration, rejecting retired legacy kwargs.
-
-    The per-field kwargs (``mode``/``refresh_mode``, ``walker``,
-    ``mesh_shards``, ``delta_full_threshold``, ``queue_delay_correction``)
-    were one-release :class:`DeprecationWarning` shims in PR 6 and are now
-    removed: any explicitly passed one (anything not ``_UNSET``) raises
-    :class:`TypeError` naming the replacement spelling.
-    """
-    legacy = {k: v for k, v in (
-        ("mode", mode), ("walker", walker), ("mesh_shards", mesh_shards),
-        ("delta_full_threshold", delta_full_threshold),
-        ("queue_delay_correction", queue_delay_correction),
-    ) if v is not _UNSET}
-    if legacy:
-        spelled = ", ".join(f"{k}={v!r}" for k, v in sorted(legacy.items()))
-        raise TypeError(
-            f"{owner}: the legacy per-field refresh kwarg(s) "
-            f"{sorted(legacy)} were removed (deprecated in the previous "
-            f"release); pass refresh=RefreshConfig({spelled}) instead "
-            "(see repro.core.refresh_config and the migration guide in "
-            "docs/ARCHITECTURE.md)")
-    return refresh if refresh is not None else RefreshConfig()

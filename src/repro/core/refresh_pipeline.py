@@ -1,4 +1,4 @@
-"""Device-resident fused refresh pipeline (§3.3 hot path, Fig. 15).
+"""Device-resident refresh pipeline (§3.3 hot path, Fig. 15).
 
 One jitted dispatch chains the whole bucket-tick estimate refresh —
 
@@ -6,20 +6,20 @@ One jitted dispatch chains the whole bucket-tick estimate refresh —
                                                       → prewarm triggers)
 
 — over packed PDGraph tables and the persistent slot arena
-(:mod:`repro.core.arena`).  Only small per-app results (ranks, histogram
-rows, triage scalars, prewarm triggers) ever cross the host boundary; the
-``(A, n_walkers)`` sample matrix lives and dies on device.
+(:mod:`repro.core.arena`).  Only small per-app results (ranks, triage
+scalars, prewarm triggers) ever cross the host boundary; the
+``(A, n_walkers)`` sample matrix and the histogram rows stay on device.
 
 Two walker backends:
 
 * ``walker="threefry"`` — the original ``_walk_core`` under vmap with the
   per-(app, refresh) fold_in chain: bit-identical demand samples to the
-  composed/looped paths, so fused ranks match them to float32 tolerance.
+  composed host path, so device ranks match it to float32 tolerance.
   The equivalence baseline.
 * ``walker="pallas"`` — the counter-RNG ``pdgraph_walk`` kernel package
   (Pallas kernel on TPU, bit-identical jnp twin elsewhere): breaks the
   threefry bottleneck and adds phase compaction; distributionally
-  equivalent (KS-tested), and the default for fused mode.
+  equivalent (KS-tested), and the default.
 
 **Delta refresh** (``refresh_ranks_delta``) is the scale path: each tick
 gathers only the dirty slots, walks just those rows, scatters their fresh
@@ -43,7 +43,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from dataclasses import dataclass
 from functools import lru_cache, partial
-from typing import Optional, Tuple
+from typing import Optional
 
 import numpy as np
 
@@ -171,19 +171,6 @@ def _triggers_from_hists(hist, lo, span, n_reach, n_walkers, delta,
     return trigger, reach
 
 
-def _prewarm_triggers(arr, graph_idx, unit_class, class_warmup, K, n_buckets,
-                      stretch):
-    """Walk-time triggers: arrival tensor -> histograms -> the shared
-    delta-conditioned quantile math at delta=0 (one code path for walk-time
-    and retrigger triggers, so the two can never drift)."""
-    W = arr.shape[1]
-    hist, lo, span, n_reach = _arrival_hists(arr, n_buckets)
-    return _triggers_from_hists(hist, lo, span, n_reach, W,
-                                jnp.zeros(arr.shape[0], jnp.float32),
-                                unit_class[graph_idx], class_warmup, K,
-                                stretch)
-
-
 def _walk_total(samples, counts, cum_trans, graph_idx, start, executed,
                 attained, key_ids, refresh_ids, base_key, seed,
                 ov_samples, ov_counts, valid, *,
@@ -201,7 +188,7 @@ def _walk_total(samples, counts, cum_trans, graph_idx, start, executed,
     arr = None
     if walker == "threefry":
         # the composed path's walker verbatim — ONE implementation carries
-        # the fold_in chain, so fused/composed bit-identity cannot drift
+        # the fold_in chain, so device/composed bit identity cannot drift
         out = _mc_walk_batch(samples, counts, cum_trans,
                              graph_idx, start, executed,
                              base_key, key_ids, refresh_ids,
@@ -259,8 +246,8 @@ def _quantile_rows(x_sorted, q):
     """Row-wise linear-interpolation quantile with COMPILE-STABLE bits.
 
     ``jnp.quantile`` is numerically fine but its lerp may or may not be
-    FMA-contracted depending on the surrounding program (full fused tick,
-    delta tick, mesh shard program all compile separately), drifting the
+    FMA-contracted depending on the surrounding program (the delta tick and
+    the mesh shard program compile separately), drifting the
     result by an ulp between pipelines.  Here the rank indices are static,
     and the optimization barrier between the multiply and the add pins the
     rounding to mul-then-add in every compilation — the sharded/unsharded
@@ -282,74 +269,6 @@ def _triage_stats(total):
     sup = _quantile_rows(srt, SUP_Q)
     opt = _quantile_rows(srt, HOPELESS_Q)
     return sup, opt, total.mean(axis=1)
-
-
-@partial(jax.jit, static_argnames=("n_walkers", "max_steps", "n_buckets",
-                                   "walker", "impl", "with_overrides",
-                                   "compact_after", "compact_shrink",
-                                   "with_prewarm", "with_triage",
-                                   "rank_in_kernel"))
-def _fused_pipeline(samples, counts, cum_trans,        # KB: (G,U,S),(G,U),(G,U,U+1)
-                    graph_idx, start, executed, attained,   # (A,) queue state
-                    key_ids, refresh_ids,                   # (A,) RNG stream ids
-                    base_key, seed,                         # threefry / counter seeds
-                    ov_samples, ov_counts,                  # (A,U,So), (A,U)
-                    valid,                                  # (A,) bool queue rows
-                    stretch,                                # (A,) wall/service EWMA
-                    unit_class, class_warmup, prewarm_k,    # prewarm tables + K
-                    qsv, qic,                               # quant tables | (1,) dummies
-                    *, n_walkers: int, max_steps: int, n_buckets: int,
-                    walker: str, impl: Optional[str], with_overrides: bool,
-                    compact_after: int, compact_shrink: int,
-                    with_prewarm: bool, with_triage: bool,
-                    rank_in_kernel: bool = False):
-    """walk → bucketize → rank (→ triage quantiles → prewarm triggers), one
-    dispatch.  Returns (ranks, probs, edges, spill, trigger, reach, sup,
-    opt, mean) — all shaped (A, ...), A padded to a power of two by the
-    caller; trigger/reach are ``None`` without ``with_prewarm``, the triage
-    scalars ``None`` without ``with_triage``.  The (A, W) sample matrix and
-    the (A, W, U) arrival tensor never reach the host.
-
-    With ``rank_in_kernel`` the walk/bucketize/rank chain collapses into one
-    :func:`pdgraph_walk_ranked` call (the VMEM-resident program on the
-    kernel path) — bit-identical outputs, no ``(A, W)`` intermediate unless
-    triage asks for the raw totals."""
-    if rank_in_kernel:
-        res = _walk_ranked(
-            samples, counts, cum_trans, graph_idx, start, executed,
-            attained, key_ids, refresh_ids, seed, ov_samples, ov_counts,
-            valid, qsv, qic, n_walkers=n_walkers, max_steps=max_steps,
-            n_buckets=n_buckets, impl=impl, with_overrides=with_overrides,
-            compact_after=compact_after, compact_shrink=compact_shrink,
-            with_prewarm=with_prewarm, with_triage=with_triage)
-        sup = opt = mean = None
-        if with_triage:
-            sup, opt, mean = _triage_stats(res["total"])
-        trigger = reach = None
-        if with_prewarm:
-            trigger, reach = _triggers_from_hists(
-                res["a_hist"], res["a_lo"], res["a_span"], res["a_reach"],
-                n_walkers, jnp.zeros(graph_idx.shape[0], jnp.float32),
-                unit_class[graph_idx], class_warmup, prewarm_k, stretch)
-        return (res["ranks"], res["probs"], res["edges"], res["spill"],
-                trigger, reach, sup, opt, mean)
-    total, arr, spill = _walk_total(
-        samples, counts, cum_trans, graph_idx, start, executed, attained,
-        key_ids, refresh_ids, base_key, seed, ov_samples, ov_counts, valid,
-        n_walkers=n_walkers, max_steps=max_steps, walker=walker, impl=impl,
-        with_overrides=with_overrides, compact_after=compact_after,
-        compact_shrink=compact_shrink, with_prewarm=with_prewarm)
-    probs, edges = to_histogram_rows_jnp(total, n_buckets)
-    ranks = gittins_rank_core(probs, edges, attained)
-    sup = opt = mean = None
-    if with_triage:
-        sup, opt, mean = _triage_stats(total)
-    trigger = reach = None
-    if with_prewarm:
-        trigger, reach = _prewarm_triggers(arr, graph_idx, unit_class,
-                                           class_warmup, prewarm_k,
-                                           n_buckets, stretch)
-    return ranks, probs, edges, spill, trigger, reach, sup, opt, mean
 
 
 @partial(jax.jit, static_argnames=("n_walkers", "max_steps", "n_buckets",
@@ -487,21 +406,6 @@ def _rank_retrigger_pipeline(d_probs, d_edges, attained_all,
     return ranks, trigger, reach
 
 
-@dataclass
-class FusedRefresh:
-    """Host-side results of one fused refresh over a slot subset (all
-    row-aligned with the ``slots`` argument)."""
-    ranks: np.ndarray                  # (A,)
-    probs: np.ndarray                  # (A, n_buckets)
-    edges: np.ndarray                  # (A, n_buckets)
-    spill: int
-    trigger: Optional[np.ndarray]      # (A, B) | None
-    reach: Optional[np.ndarray]        # (A, B) | None
-    sup: Optional[np.ndarray]          # (A,) | None  (with_triage)
-    opt: Optional[np.ndarray]
-    mean: Optional[np.ndarray]
-
-
 def _prewarm_args(packed, prewarm_table, io=None):
     """The ``PrewarmTable`` constants, resident on the device: uploaded once
     per table object (a KB repack builds a new one) through ``io`` (a
@@ -550,7 +454,7 @@ def _quant_dummies():
 
 def _dispatch_rows(qs: QueueState, slots: np.ndarray, packed: PackedKB,
                    prewarm_table, pad_to: Optional[int] = None, io=None):
-    """Shared host-side marshalling for the refresh entry points: padded
+    """Host-side marshalling for the delta dispatch: padded
     row gather (host arrays), override-width trim, prewarm constants
     (resident, through ``io``)."""
     gi, start, executed, attained, kid, rid, stretch, ovs, ovc = \
@@ -564,83 +468,14 @@ def _dispatch_rows(qs: QueueState, slots: np.ndarray, packed: PackedKB,
 
 
 def _store_results(qs: QueueState, slots: np.ndarray, n_buckets: int,
-                   n_classes, sup, opt, mean, trigger, reach) -> None:
-    """Write one dispatch's per-slot results into the store's host mirrors
-    (the single write-back path for the refresh entry points)."""
+                   n_classes, sup, opt, mean) -> None:
+    """Write one dispatch's per-slot triage scalars into the store's host
+    mirrors."""
     qs.ensure_result_rows(n_buckets, n_classes)
     if sup is not None:
         qs.sup[slots] = sup
         qs.opt[slots] = opt
         qs.mean[slots] = mean
-    if trigger is not None:
-        qs.trig[slots] = trigger
-        qs.reach[slots] = reach
-
-
-def refresh_ranks_fused(packed: PackedKB, qs: QueueState, base_key, seed,
-                        *, slots: Optional[np.ndarray] = None,
-                        n_walkers: int = 512, max_steps: int = 64,
-                        n_buckets: int = N_BUCKETS, walker: str = "pallas",
-                        impl: Optional[str] = None,
-                        compact_after: int = 16, compact_shrink: int = 4,
-                        prewarm_table=None, prewarm_k: float = 0.5,
-                        with_triage: bool = False,
-                        rank_in_kernel: Optional[bool] = None
-                        ) -> FusedRefresh:
-    """One fused refresh over a slot subset (default: every occupied slot).
-
-    Returns a :class:`FusedRefresh` of host arrays — the (A, n_walkers)
-    sample matrix stays on device.  Fresh triage scalars and prewarm
-    trigger/reach rows are also written into the store's host mirrors, so
-    the planner can read arrival rows without holding this return value.
-    Does NOT bump refresh ids; callers bump after consuming.
-
-    ``rank_in_kernel`` (default: on for ``walker="pallas"``) runs the
-    one-pass VMEM-resident program (``pdgraph_walk_ranked``) instead of the
-    walk → histogram → rank composition — bit-identical results."""
-    if slots is None:
-        slots = qs.occupied()
-    A = len(slots)
-    if A == 0:
-        # same field contract as the dispatch path: optional outputs are
-        # None exactly when their feature is off, zero-length otherwise
-        z = np.zeros((0, n_buckets), np.float32)
-        zs = np.zeros(0, np.float32)
-        zt = (np.zeros((0, prewarm_table.n_classes), np.float32)
-              if prewarm_table is not None else None)
-        tri = zs if with_triage else None
-        return FusedRefresh(zs, z, z, 0, zt, zt, tri, tri, tri)
-    io = _Crossings()
-    gi, start, executed, attained, kid, rid, stretch, ovs, ovc, with_ov, \
-        uc, wt = _dispatch_rows(qs, slots, packed, prewarm_table, io=io)
-    with_pw = prewarm_table is not None
-    rank_in_kernel, qsv, qic = _ranked_args(packed, walker, impl,
-                                            rank_in_kernel)
-    # the row arrays go to the jit as host arrays (its C++ argument path)
-    ranks, probs, edges, spill, trigger, reach, sup, opt, mean = \
-        _fused_pipeline(
-            packed.samples, packed.counts, packed.cum_trans,
-            gi, start, executed, attained, kid, rid,
-            base_key, np.uint32(int(seed) & 0xFFFFFFFF), ovs, ovc,
-            np.arange(len(gi)) < A, stretch,
-            uc, wt, io.scalar(prewarm_k), qsv, qic,
-            n_walkers=n_walkers, max_steps=max_steps, n_buckets=n_buckets,
-            walker=walker, impl=impl, with_overrides=with_ov,
-            compact_after=compact_after, compact_shrink=compact_shrink,
-            with_prewarm=with_pw, with_triage=with_triage,
-            rank_in_kernel=rank_in_kernel)
-    out = FusedRefresh(
-        np.asarray(ranks)[:A], np.asarray(probs)[:A], np.asarray(edges)[:A],
-        int(spill),
-        np.asarray(trigger)[:A] if with_pw else None,
-        np.asarray(reach)[:A] if with_pw else None,
-        np.asarray(sup)[:A] if with_triage else None,
-        np.asarray(opt)[:A] if with_triage else None,
-        np.asarray(mean)[:A] if with_triage else None)
-    _store_results(qs, slots, n_buckets,
-                   prewarm_table.n_classes if with_pw else None,
-                   out.sup, out.opt, out.mean, out.trigger, out.reach)
-    return out
 
 
 @dataclass
@@ -863,8 +698,7 @@ def refresh_ranks_delta(packed: PackedKB, qs: QueueState, base_key, seed,
             _store_results(qs, walked, n_buckets,
                            prewarm_table.n_classes if with_pw else None,
                            *(got[k][:D] if with_triage else None
-                             for k in ("sup", "opt", "mean")),
-                           None, None)
+                             for k in ("sup", "opt", "mean")))
         if "trigger" in got:
             if retrigger:
                 qs.trig = np.array(got["trigger"])   # writable, whole arena

@@ -11,10 +11,9 @@ same ``on_*`` callbacks; in a production deployment these arrive over RPC
 """
 from __future__ import annotations
 
-import dataclasses
 import itertools
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -30,11 +29,9 @@ from repro.core.posterior import (END, Observation, PosteriorConfig,
                                   PosteriorState, row_width)
 from repro.core.prewarm import (PrewarmPlan, PrewarmSignal,
                                 build_prewarm_table)
-from repro.core.refresh_config import (_UNSET, RefreshConfig,
-                                       resolve_refresh_config)
+from repro.core.refresh_config import RefreshConfig
 from repro.core.refresh_mesh import RefreshMesh, refresh_ranks_mesh
-from repro.core.refresh_pipeline import (refresh_ranks_delta,
-                                         refresh_ranks_fused)
+from repro.core.refresh_pipeline import refresh_ranks_delta
 from repro.runtime.tracing import span
 
 
@@ -65,14 +62,9 @@ class HermesScheduler:
                  K: float = 0.5, n_buckets: int = 10,
                  refine: bool = True, prewarm: bool = True,
                  mc_walkers: int = 512, seed: int = 0,
-                 batched: bool = True,
                  refresh: Optional[RefreshConfig] = None,
-                 mode=_UNSET, walker=_UNSET,
                  compact_after: int = 16, compact_shrink: int = 4,
                  warmup_table: Optional[Dict[str, float]] = None,
-                 delta_full_threshold=_UNSET,
-                 queue_delay_correction=_UNSET,
-                 mesh_shards=_UNSET,
                  posterior: Optional[PosteriorConfig] = None):
         self.kb = knowledge_base
         self.policy: Policy = make_policy(policy) if policy != "gittins" \
@@ -86,25 +78,12 @@ class HermesScheduler:
         self._mc_walkers_base = mc_walkers
         self._walker_cap: Optional[int] = None
         # The refresh backbone is configured by ONE validated RefreshConfig
-        # (see repro.core.refresh_config); the retired per-field kwargs are
-        # kept in the signature only so passing one raises the migration
-        # TypeError instead of an anonymous unexpected-keyword error.
-        if mode is None:
-            mode = _UNSET      # legacy "derive from ``batched``" spelling
-        rc = resolve_refresh_config(
-            refresh, owner="HermesScheduler",
-            mode=mode, walker=walker, mesh_shards=mesh_shards,
-            delta_full_threshold=delta_full_threshold,
-            queue_delay_correction=queue_delay_correction)
-        if refresh is None and mode is _UNSET:
-            # bare construction keeps the pre-RefreshConfig default: the
-            # ``batched`` flag picks composed vs looped (the simulator's
-            # SimConfig is where fused_delta is the default)
-            rc = dataclasses.replace(
-                rc, mode="composed" if batched else "looped")
+        # (see repro.core.refresh_config).  A bare scheduler refreshes on
+        # the composed host path; the simulator's SimConfig is where
+        # fused_delta is the default.
+        rc = refresh if refresh is not None else RefreshConfig(mode="composed")
         self.refresh_config = rc
         self.mode = rc.mode
-        self.batched = self.mode != "looped"
         self.delta_full_threshold = rc.delta_full_threshold
         self.queue_delay_correction = rc.queue_delay_correction
         # Mesh sharding: partition the slot arena over mesh_shards devices
@@ -122,8 +101,6 @@ class HermesScheduler:
         self.lane_balance = rc.lane_balance
         self.compact_after = compact_after
         self.compact_shrink = compact_shrink
-        if hasattr(self.policy, "vectorized"):
-            self.policy.vectorized = self.batched
         self.apps: Dict[str, AppRuntime] = {}
         # live subset of `apps`: the refresh tick iterates only this, and
         # retired apps drop their sample arrays, so an unbounded open-arrival
@@ -133,14 +110,14 @@ class HermesScheduler:
         self._base_key = jax.random.PRNGKey(seed)
         self._app_seq = itertools.count()
         self._packed = None               # (kb versions, PackedKB) cache
-        self._qstate = None               # fused-mode queue buffers (lazy)
+        self._qstate = None               # device-path slot store (lazy)
         self.fused_spill = 0              # walkers truncated by compaction
         # single-arena delta dispatches and their host<->device transfers
         self.refresh_stats = {"event_dispatches": 0, "tick_dispatches": 0,
                               "h2d": 0, "d2h": 0, "d2h_async": 0}
         self.warmup_table = warmup_table  # per-key warm-up cost overrides
         self._prewarm_tab = None          # (kb token, PrewarmTable) cache
-        self.prewarm_plan: Optional[PrewarmPlan] = None   # last fused plan
+        self.prewarm_plan: Optional[PrewarmPlan] = None   # last device plan
         # mesh fast path: app_id -> rank dict maintained incrementally (only
         # re-ranked slots are touched per tick); callers get a shallow copy
         self._mesh_ranks: Optional[Dict[str, float]] = None
@@ -168,8 +145,9 @@ class HermesScheduler:
 
     # ------------------------------------------------------------ internals
     def _app_key(self, app: AppRuntime):
-        """Deterministic per-(app, refresh) key — mode-independent, so the
-        looped and batched paths draw bit-identical MC samples."""
+        """Deterministic per-(app, refresh) key — the same chain the batched
+        walk derives, so a single-app refresh draws bit-identical MC
+        samples."""
         k = jax.random.fold_in(self._base_key, app.key_id)
         return jax.random.fold_in(k, app.refreshes)
 
@@ -180,31 +158,28 @@ class HermesScheduler:
                             pack_graphs(self.kb, self.t_in, self.t_out))
         return self._packed[1]
 
-    def _fused_active(self) -> bool:
-        """The fused pipeline computes Gittins ranks AND the composite
-        policies' triage quantiles on device, so it engages for every
-        fused-capable policy (gittins, hermes_ddl, lstf at the stock
-        quantiles); anything else still needs raw host-side demand samples
-        and falls back to the composed path."""
-        return self.mode in ("fused", "fused_delta") and \
-            bool(getattr(self.policy, "fused_capable", False))
-
     def _delta_active(self) -> bool:
-        return self.mode == "fused_delta" and self._fused_active()
+        """The device pipeline computes Gittins ranks AND the composite
+        policies' triage quantiles, so in ``fused_delta`` mode it engages
+        for every fused-capable policy (gittins, hermes_ddl, lstf at the
+        stock quantiles); anything else still needs raw host-side demand
+        samples and falls back to the composed path."""
+        return self.mode == "fused_delta" and \
+            bool(getattr(self.policy, "fused_capable", False))
 
     @property
     def _with_triage(self) -> bool:
-        """Composite fused policies need the device triage scalars; plain
-        Gittins skips computing them (keeps the rank-only arm's cost and
-        jit cache unchanged)."""
+        """Composite policies need the device triage scalars; plain
+        Gittins skips computing them (keeps its dispatch's cost and jit
+        cache unchanged)."""
         return type(self.policy) is not GittinsPolicy
 
     @property
     def prewarm_batched(self) -> bool:
-        """True when prewarm planning rides the fused refresh dispatch (one
-        batched PrewarmPlan per tick) instead of the legacy per-app
+        """True when prewarm planning rides the device refresh dispatch
+        (one batched PrewarmPlan per tick) instead of the per-app
         ``prewarm_signals`` calls."""
-        return self.prewarm_enabled and self._fused_active()
+        return self.prewarm_enabled and self._delta_active()
 
     def _prewarm_table(self):
         """PrewarmTable aligned with the current packed KB (rebuilt whenever
@@ -220,7 +195,7 @@ class HermesScheduler:
         return self._prewarm_tab[1]
 
     def take_prewarm_plan(self) -> Optional[PrewarmPlan]:
-        """Hand the last fused-dispatch PrewarmPlan to the host (simulator /
+        """Hand the last device-dispatch PrewarmPlan to the host (simulator /
         engine) exactly once; None when nothing was planned since the last
         take."""
         plan, self.prewarm_plan = self.prewarm_plan, None
@@ -243,7 +218,7 @@ class HermesScheduler:
         """PackedKB when the incremental QueueState may be mutated in place;
         None when there is none or the KB was repacked since it was built
         (then the stale buffers are dropped — unit indices/table shapes may
-        have changed — and rebuilt wholesale on the next fused refresh)."""
+        have changed — and rebuilt wholesale on the next device refresh)."""
         if self._qstate is None:
             return None
         packed = self._packed_kb()
@@ -278,9 +253,8 @@ class HermesScheduler:
         the whole set instead of one walk per application."""
         if not apps:
             return
-        if not self.batched or len(apps) == 1:
-            for a in apps:
-                self._refresh_view(a)
+        if len(apps) == 1:
+            self._refresh_view(apps[0])
             return
         packed = self._packed_kb()
         gi = np.asarray([packed.graph_index[a.app_name] for a in apps],
@@ -297,57 +271,13 @@ class HermesScheduler:
             overrides=[a.overrides or None for a in apps],
             n_walkers=self.mc_walkers)
         total = np.maximum(rem, 0.0)
-        # float32 addend: bit-identical to the looped path's
+        # float32 addend: bit-identical to the single-app path's
         # `attained + np.maximum(rem, 0.0)` float32 scalar promotion
         total += np.asarray([a.attained for a in apps],
                             np.float32)[:, None]
         for a, row in zip(apps, total):
             a.refreshes += 1
             self._make_view(a, row)
-
-    def _refresh_views_fused(self, apps: List[AppRuntime],
-                             now: float) -> None:
-        """Fused refresh: one device dispatch re-estimates, bucketizes and
-        ranks the stale set; views carry the (n_buckets,) histogram rows and
-        the device rank — never the (A, n_walkers) sample matrix.  For the
-        composite policies the dispatch also returns the triage quantiles.
-        With prewarming enabled the SAME dispatch scatters the per-(app,
-        backend-class) trigger rows into the slot store, read back as a
-        PrewarmPlan for the host to take (no per-app planning loop)."""
-        if not apps:
-            return
-        qs = self._ensure_qstate()
-        slots = np.asarray([qs.slot[a.app_id] for a in apps], np.int64)
-        tab = self._prewarm_table() if self.prewarm_batched else None
-        out = refresh_ranks_fused(
-            self._packed[1], qs, self._base_key, self._seed,
-            slots=slots, n_walkers=self.mc_walkers,
-            n_buckets=self.n_buckets, walker=self.walker,
-            compact_after=self.compact_after,
-            compact_shrink=self.compact_shrink,
-            prewarm_table=tab, prewarm_k=self.K,
-            with_triage=self._with_triage,
-            rank_in_kernel=self.rank_in_kernel)
-        self.fused_spill += out.spill
-        if tab is not None:
-            self._stash_plan(PrewarmPlan.from_store(qs, slots, now, tab))
-        triage = out.sup is not None
-        for i, a in enumerate(apps):
-            a.refreshes += 1
-            a.view = AppView(app_id=a.app_id, tenant=a.tenant,
-                             arrival=a.arrival, attained=a.attained,
-                             total_samples=None, deadline=a.deadline,
-                             oracle_remaining=a.oracle_remaining,
-                             hist=(out.probs[i], out.edges[i]),
-                             fused_rank=float(out.ranks[i]),
-                             demand_sup=float(out.sup[i]) if triage else None,
-                             demand_opt=float(out.opt[i]) if triage else None,
-                             demand_mean=float(out.mean[i]) if triage
-                             else None)
-        qs.bump_refresh(slots)
-        # these slots' estimates are fresh now — clear their pending marks
-        # so a later delta tick doesn't re-walk covered work
-        qs.clear_dirty(slots)
 
     def _priorities_delta(self, now: float,
                           app_ids: Optional[List[str]] = None, *,
@@ -516,52 +446,30 @@ class HermesScheduler:
         (``ranks_row`` — the delta tick's full-arena rank vector, or the
         mesh's host rank mirror) and the triage scalar mirrors are gathered
         per-slot in vectorized reads and handed to the policy's
-        ``ranks_columns`` twin.  No AppView objects are minted on this path
-        — formerly the last per-app Python loop on the mesh hot path.
-        ``attained``/``deadline`` come from the float64 host records (the
-        float32 store mirrors round), keeping rank values bit-identical to
-        the retired view-minting loop."""
+        ``ranks_columns`` twin; a policy without one (plain Gittins) takes
+        the device ranks as they are.  No AppView objects are minted on
+        this path.  ``attained``/``deadline`` come from the float64 host
+        records (the float32 store mirrors round)."""
         if not live:
             return {}
         n = len(live)
         slots = np.asarray([qs.slot[a.app_id] for a in live], np.int64)
         ids = [a.app_id for a in live]
         g = np.asarray(ranks_row[slots], np.float32)
-        if type(self.policy) is GittinsPolicy:
+        if not getattr(self.policy, "columns_capable", False):
             return dict(zip(ids, g.tolist()))
-        if getattr(self.policy, "columns_capable", False) \
-                and self._with_triage:
-            attained = np.fromiter((a.attained for a in live),
-                                   np.float64, count=n)
-            deadline = np.fromiter(
-                (np.inf if a.deadline is None else a.deadline
-                 for a in live), np.float64, count=n)
-            ranks = self.policy.ranks_columns(
-                now, g=g,
-                sup=qs.sup[slots].astype(np.float64),
-                opt=qs.opt[slots].astype(np.float64),
-                mean=qs.mean[slots].astype(np.float64),
-                attained=attained, deadline=deadline)
-            return dict(zip(ids, (float(r) for r in ranks)))
-        # fused-capable but not columns-capable policy: mint views (the
-        # pre-vectorization consumption, kept as the general fallback)
-        triage = self._with_triage
-        for a, s in zip(live, slots.tolist()):
-            v = a.view
-            if v is None:
-                v = AppView(app_id=a.app_id, tenant=a.tenant,
-                            arrival=a.arrival, attained=a.attained,
-                            total_samples=None, deadline=qs.get_deadline(s),
-                            oracle_remaining=a.oracle_remaining)
-                a.view = v
-            v.attained = a.attained
-            v.fused_rank = float(ranks_row[s])
-            if triage:
-                v.demand_sup = float(qs.sup[s])
-                v.demand_opt = float(qs.opt[s])
-                v.demand_mean = float(qs.mean[s])
-        ranks = self.policy.ranks([a.view for a in live], now)
-        return {a.app_id: float(r) for a, r in zip(live, ranks)}
+        attained = np.fromiter((a.attained for a in live), np.float64,
+                               count=n)
+        deadline = np.fromiter(
+            (np.inf if a.deadline is None else a.deadline for a in live),
+            np.float64, count=n)
+        ranks = self.policy.ranks_columns(
+            now, g=g,
+            sup=qs.sup[slots].astype(np.float64),
+            opt=qs.opt[slots].astype(np.float64),
+            mean=qs.mean[slots].astype(np.float64),
+            attained=attained, deadline=deadline)
+        return dict(zip(ids, (float(r) for r in ranks)))
 
     def _posterior_flush(self, qs, walked: np.ndarray) -> None:
         """Fold the pending observation buffer into the per-graph conjugate
@@ -652,11 +560,9 @@ class HermesScheduler:
         app.attained += service_delta
         app.attained_in_unit += service_delta
         if app.view is not None:
+            # the cached histogram of TOTAL demand stays valid: the next
+            # priorities() re-ranks from it at the new attained
             app.view.attained = app.attained
-            # rank depends on attained: drop the cached device rank (the
-            # cached histogram of TOTAL demand stays valid) so the next
-            # priorities() re-ranks from the hist at the new attained
-            app.view.fused_rank = None
         if self._qstate is not None and app_id in self._qstate.slot:
             self._qstate.add_progress(app_id, service_delta)
         if isinstance(self.policy, VTCPolicy):
@@ -757,7 +663,7 @@ class HermesScheduler:
         (``None`` restores the configured depth).  Cheaper refresh ticks
         exactly when the queue is largest; capped estimates are noisier, so
         hosts only engage this past the degradation watermark.  The cap is
-        clamped to a power of two so the fused dispatch adds at most one
+        clamped to a power of two so the device dispatch adds at most one
         extra jit trace per distinct cap."""
         if cap is None:
             self._walker_cap = None
@@ -829,20 +735,16 @@ class HermesScheduler:
     def demand_triage(self, app_id: str) -> Optional[Tuple[float, float]]:
         """(attained service, optimistic TOTAL demand) of one application —
         the same instance-level estimate the composite policies' hopeless
-        gate reads: the device triage scalar in fused mode, the HOPELESS_Q
-        sample quantile on the host path.  ``None`` before the app's first
-        view refresh (admission falls back to its name-level prior)."""
+        gate reads on the host path: the HOPELESS_Q sample quantile of its
+        view.  ``None`` without a view (before the app's first composed
+        refresh, and always on the device path, which mints none);
+        admission then falls back to its name-level prior."""
         from repro.core.policies import HOPELESS_Q
         app = self.apps.get(app_id)
         if app is None or app.done or app.view is None:
             return None
-        v = app.view
-        if v.demand_opt is not None:
-            return app.attained, float(v.demand_opt)
-        if v.total_samples is not None:
-            return app.attained, float(np.quantile(v.total_samples,
-                                                   HOPELESS_Q))
-        return None
+        return app.attained, float(np.quantile(app.view.total_samples,
+                                               HOPELESS_Q))
 
     def set_oracle(self, app_id: str, remaining: float) -> None:
         app = self.apps[app_id]
@@ -878,18 +780,7 @@ class HermesScheduler:
                 return {}
             ranks = self.policy.ranks(live, now)
             return {a.app_id: float(r) for a, r in zip(live, ranks)}
-        if self._fused_active():
-            stale = [a for a in live if a.view is None]
-            self._refresh_views_fused(stale, now)
-        else:
-            # a view minted by an earlier fused dispatch carries device
-            # scalars but no sample array; if the policy has since lost
-            # fused eligibility (quantiles re-tuned mid-run), such views
-            # are both unusable by the host quantile path and pinned to
-            # the stock quantiles — re-estimate them host-side
-            stale = [a for a in live
-                     if a.view is None or a.view.total_samples is None]
-            self._refresh_views(stale)
+        self._refresh_views([a for a in live if a.view is None])
         views = [a.view for a in live]
         if not views:
             return {}
@@ -955,8 +846,8 @@ class HermesScheduler:
                      resample: bool = False) -> Dict[str, float]:
         """The bucket-tick refresh: re-rank the whole queue.  With
         ``resample=True`` every live demand estimate is first re-drawn from
-        the PDGraphs (one batched MC dispatch in batched mode, one walk per
-        app in looped mode) — the full Fig. 15 refresh cost.  In
+        the PDGraphs (one batched MC dispatch) — the full Fig. 15 refresh
+        cost.  In
         ``fused_delta`` mode resampling is demand-driven instead: only the
         slots whose PDGraph position changed since the last tick (the dirty
         set) are re-walked, everyone else re-ranks in place from persisted
@@ -971,7 +862,7 @@ class HermesScheduler:
                            service_s: float) -> None:
         """Queueing-delay correction feed (§3.4 refinement): hosts report
         each task's observed queue wait at start; the scheduler keeps a
-        per-app EWMA of the wall/service *stretch* factor, which the fused
+        per-app EWMA of the wall/service *stretch* factor, which the device
         prewarm reduction uses to convert arrival quantiles (cumulative
         service seconds) into wall-clock trigger times.  No-op unless
         ``queue_delay_correction`` is enabled (default off — the §3.4 paper

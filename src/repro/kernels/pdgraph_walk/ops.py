@@ -2,8 +2,9 @@
 
 ``pdgraph_walk`` runs the whole-queue remaining-service walk over packed
 knowledge-base tables and returns the (A, n_walkers) totals as a *device*
-array — it is designed to be traced inline into the fused refresh pipeline
-(`repro.core.refresh`) so the sample matrix never crosses the host boundary.
+array — it is designed to be traced inline into the device refresh
+pipeline (`repro.core.refresh_pipeline`) so the sample matrix never crosses
+the host boundary.
 
 Implementation dispatch:
   impl="pallas"  the Pallas kernel (compiled on TPU, interpreter elsewhere)

@@ -48,8 +48,7 @@ from repro.core.admission import (ADMIT, DEFER, SHED_DEFER_EXPIRED,
 from repro.core.hermeslet import HermesLet
 from repro.core.pdgraph import PDGraph
 from repro.core.posterior import PosteriorConfig
-from repro.core.refresh_config import (RefreshConfig, _UNSET,
-                                       resolve_refresh_config)
+from repro.core.refresh_config import RefreshConfig
 from repro.core.scheduler import HermesScheduler
 from repro.runtime.fault_tolerance import (BackendStragglerWatchdog,
                                            FailureInjector, HeartbeatRegistry,
@@ -86,14 +85,9 @@ class SimConfig:
     engine: str = "calendar"
     # priority-refresh pipeline configuration: ONE validated RefreshConfig
     # (mode / walker / mesh_shards / delta_full_threshold /
-    # queue_delay_correction — see repro.core.refresh_config).  The
-    # retired per-field kwargs below raise TypeError with the RefreshConfig
-    # spelling to migrate to.
+    # queue_delay_correction — see repro.core.refresh_config); None is the
+    # default RefreshConfig(), the fused_delta device path
     refresh: Optional[RefreshConfig] = None
-    refresh_mode: Optional[str] = None            # removed -> refresh
-    walker: Optional[str] = None                  # removed -> refresh
-    mesh_shards: Optional[int] = None             # removed -> refresh
-    queue_delay_correction: Optional[bool] = None  # removed -> refresh
     # epwq prefetch window: how many upcoming trajectory units (starting at
     # the one being spawned) get their backend keys prefetched when tasks
     # enqueue.  1 = the CachedAttention-style current-unit-only baseline.
@@ -135,18 +129,8 @@ class SimConfig:
                 "(the default) is the supported engine. The heap loop is "
                 "retained only as the slow-tier bit-equivalence oracle.",
                 DeprecationWarning, stacklevel=3)
-        kw = {}
-        if self.refresh_mode is not None:
-            kw["mode"] = self.refresh_mode
-        if self.walker is not None:
-            kw["walker"] = self.walker
-        if self.mesh_shards is not None:
-            kw["mesh_shards"] = self.mesh_shards
-        if self.queue_delay_correction is not None:
-            kw["queue_delay_correction"] = self.queue_delay_correction
-        # stacklevel: resolve -> __post_init__ -> generated __init__ -> user
-        self.refresh = resolve_refresh_config(self.refresh, owner="SimConfig",
-                                              stacklevel=4, **kw)
+        if self.refresh is None:
+            self.refresh = RefreshConfig()
 
 
 @dataclass(eq=False)   # identity equality: tasks are unique live objects,
@@ -684,16 +668,17 @@ class ClusterSim:
     def _tick_admission(self) -> None:
         """Mid-run sweep: hopeless apps shed terminally; zero-progress
         best-effort work of over-share tenants defers under pressure.  The
-        optimistic total comes from the arena's device triage scalar when
-        the fused pipeline maintains one, else the per-name prior."""
+        optimistic total comes from the scheduler's host-path view when it
+        has one (``demand_triage``), else the per-name prior: the device
+        path mints no view, so there the prior serves."""
         pressure = self._pressure()
         rows = []
         for app_id, sim in self.apps.items():
             if sim.finished is not None or app_id in self._shed:
                 continue
-            # the SAME instance-level estimate the policies' hopeless gate
-            # reads (MC demand conditioned on actual progress); the
-            # name-level prior only covers apps with no view yet
+            # on the composed path, the SAME instance-level estimate the
+            # policies' hopeless gate reads (MC demand conditioned on actual
+            # progress); the name-level prior covers apps with no view
             triage = self.sched.demand_triage(app_id)
             if triage is not None:
                 attained, opt_total = triage
@@ -870,8 +855,8 @@ class ClusterSim:
         self._plan_prewarms(sim.inst.app_id)
 
     def _plan_prewarms(self, app_id: str):
-        """Legacy per-app one-hop planning — only for the non-fused refresh
-        modes; in fused mode the batched PrewarmPlan from the refresh
+        """Per-app one-hop planning — only for the composed refresh path;
+        on the device path the batched PrewarmPlan from the refresh
         dispatch covers every downstream unit (``_apply_prewarm_plan``)."""
         if self.cfg.prewarm_mode != "hermes" or self.sched.prewarm_batched:
             return
@@ -881,7 +866,7 @@ class ClusterSim:
         self._push_signals(sigs)
 
     def _apply_prewarm_plan(self):
-        """Consume the batched PrewarmPlan computed inside the last fused
+        """Consume the batched PrewarmPlan computed inside the last device
         refresh dispatch (one plan per tick, all apps at once)."""
         plan = self.sched.take_prewarm_plan()
         if plan is not None:

@@ -1,9 +1,11 @@
-"""Batched (whole-queue) refresh vs the seed's looped per-app path.
+"""Batched (whole-queue) composed refresh vs the same queue refreshed one
+application at a time.
 
 The batched refresh packs every PDGraph into shared padded unit tables and
-derives per-(app, refresh) RNG keys by fold_in — exactly the chain the looped
-path uses — so the two modes must produce *identical* demand samples,
-histograms, and priority orderings, not merely statistically similar ones.
+derives per-(app, refresh) RNG keys by fold_in — exactly the chain the
+single-app path (``priorities(now, [app_id])``) uses — so the two must
+produce *identical* demand samples, histograms, and priority orderings, not
+merely statistically similar ones.
 """
 import numpy as np
 import pytest
@@ -20,10 +22,10 @@ def kb():
     return build_knowledge_base(n_trials=60, seed=3)
 
 
-def _filled_scheduler(kb, batched: bool, n_apps: int = 24,
+def _filled_scheduler(kb, n_apps: int = 24,
                       policy: str = "gittins") -> HermesScheduler:
     s = HermesScheduler(kb, policy=policy, t_in=T_IN, t_out=T_OUT,
-                        mc_walkers=32, seed=11, batched=batched)
+                        mc_walkers=32, seed=11)
     names = sorted(kb)
     for i in range(n_apps):
         aid = f"a{i:03d}"
@@ -31,6 +33,16 @@ def _filled_scheduler(kb, batched: bool, n_apps: int = 24,
                      tenant=f"t{i % 4}", deadline=200.0 + 3.0 * i)
         s.on_progress(aid, 0.05 * i)
     return s
+
+
+def _per_app(s: HermesScheduler, now: float, app_ids=None) -> dict:
+    """The looped reference: every application refreshed and ranked alone,
+    through the single-app ``priorities(now, [app_id])`` path."""
+    ids = list(s._live) if app_ids is None else app_ids
+    out = {}
+    for i in ids:
+        out.update(s.priorities(now, [i]))
+    return out
 
 
 def test_batched_walker_matches_per_graph_walk(kb):
@@ -54,10 +66,10 @@ def test_batched_walker_matches_per_graph_walk(kb):
 
 
 def test_looped_and_batched_priorities_identical(kb):
-    """Fixed seed: the looped baseline and the batched refresh produce the
+    """Fixed seed: the per-app loop and the batched refresh produce the
     same ranks and therefore the same priority ordering."""
-    r_loop = _filled_scheduler(kb, batched=False).priorities(10.0)
-    r_batch = _filled_scheduler(kb, batched=True).priorities(10.0)
+    r_loop = _per_app(_filled_scheduler(kb), 10.0)
+    r_batch = _filled_scheduler(kb).priorities(10.0)
     assert sorted(r_loop) == sorted(r_batch)
     ids = sorted(r_loop)
     vl = np.asarray([r_loop[i] for i in ids])
@@ -69,19 +81,20 @@ def test_looped_and_batched_priorities_identical(kb):
 
 def test_modes_agree_after_unit_finish_with_refinement(kb):
     """Online refinement overrides flow through the batched override tables
-    identically to the looped per-app table patch."""
+    identically to the single-app walk's per-graph table patch."""
     out = {}
     for batched in (False, True):
         s = HermesScheduler(kb, t_in=T_IN, t_out=T_OUT, mc_walkers=32,
-                            seed=7, batched=batched, refine=True)
+                            seed=7, refine=True)
+        rank = s.priorities if batched else (lambda now: _per_app(s, now))
         for i in range(8):
             s.on_arrival(f"b{i}", "CG", now=float(i))
-        s.priorities(8.0)       # refresh everyone once
+        rank(8.0)               # refresh everyone once
         for i in range(4):
             s.on_unit_finish(f"b{i}", "plan",
                              {"in": 500, "out": 280, "par": 1},
                              9.0, "generate")
-        out[batched] = s.priorities(10.0)
+        out[batched] = rank(10.0)
     ids = sorted(out[False])
     vl = np.asarray([out[False][i] for i in ids])
     vb = np.asarray([out[True][i] for i in ids])
@@ -89,7 +102,7 @@ def test_modes_agree_after_unit_finish_with_refinement(kb):
 
 
 def test_priorities_subset_matches_full(kb):
-    s = _filled_scheduler(kb, batched=True)
+    s = _filled_scheduler(kb)
     full = s.priorities(10.0)
     some = list(full)[:5]
     sub = s.priorities(10.0, app_ids=some)
@@ -99,7 +112,7 @@ def test_priorities_subset_matches_full(kb):
 
 
 def test_refresh_tick_resample_redraws_estimates(kb):
-    s = _filled_scheduler(kb, batched=True, n_apps=8)
+    s = _filled_scheduler(kb, n_apps=8)
     s.refresh_tick(5.0)
     before = {a.app_id: a.view.total_samples.copy()
               for a in s.apps.values()}
@@ -111,12 +124,10 @@ def test_refresh_tick_resample_redraws_estimates(kb):
 
 
 def test_deadline_policy_modes_agree(kb):
-    """The vectorized quantile path in hermes_ddl ranks like the looped
-    per-app path."""
-    r_loop = _filled_scheduler(kb, batched=False,
-                               policy="hermes_ddl").priorities(10.0)
-    r_batch = _filled_scheduler(kb, batched=True,
-                                policy="hermes_ddl").priorities(10.0)
+    """The vectorized quantile path in hermes_ddl ranks like the per-app
+    path."""
+    r_loop = _per_app(_filled_scheduler(kb, policy="hermes_ddl"), 10.0)
+    r_batch = _filled_scheduler(kb, policy="hermes_ddl").priorities(10.0)
     ids = sorted(r_loop)
     vl = np.asarray([r_loop[i] for i in ids])
     vb = np.asarray([r_batch[i] for i in ids])

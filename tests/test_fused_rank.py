@@ -273,18 +273,19 @@ def test_rank_in_kernel_config_resolution():
         RefreshConfig(mesh_shards=2, lane_balance=-1.0)
 
 
-@pytest.mark.parametrize("mode", ["fused", "fused_delta"])
-def test_pipeline_rank_in_kernel_bit_identity(kb, mode):
+@pytest.mark.parametrize("mesh", [None, 1], ids=["fused_delta", "mesh1"])
+def test_pipeline_rank_in_kernel_bit_identity(kb, mesh):
     """The one-pass program and the legacy walk -> histogram -> rank
-    composition return identical priorities across ticks with churn."""
-    a = _filled(kb, rik=True, mode=mode)
-    b = _filled(kb, rik=False, mode=mode)
-    _check(f"{mode} tick1", a.priorities(10.0), b.priorities(10.0))
+    composition return identical priorities across ticks with churn, on
+    the single arena and on a one-shard mesh."""
+    a = _filled(kb, rik=True, mesh=mesh)
+    b = _filled(kb, rik=False, mesh=mesh)
+    _check(f"mesh={mesh} tick1", a.priorities(10.0), b.priorities(10.0))
     for s in (a, b):
         for i in range(0, 24, 3):
             s.on_progress(f"a{i:03d}", 0.7)
         s.on_unit_start("a004", s.apps["a004"].current_unit, 11.0)
-    _check(f"{mode} tick2", a.priorities(12.0), b.priorities(12.0))
+    _check(f"mesh={mesh} tick2", a.priorities(12.0), b.priorities(12.0))
 
 
 def test_pipeline_rank_in_kernel_with_posterior(kb):

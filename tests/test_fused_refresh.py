@@ -1,6 +1,7 @@
-"""Fused (device-resident) refresh pipeline vs the composed batched path.
+"""Device-resident refresh pipeline (``fused_delta``) vs the composed
+batched host path.
 
-With ``walker="threefry"`` the fused pipeline draws bit-identical demand
+With ``walker="threefry"`` the device pipeline draws bit-identical demand
 samples to the composed path (same fold_in chain through the same
 `_walk_core`); the only divergence is float32-on-device vs float64-on-host
 bucketization, so ranks must agree to float32 tolerance — including under
@@ -41,10 +42,10 @@ def _vals(ranks):
 
 
 def test_fused_threefry_matches_composed_mixed_graphs(kb):
-    """Acceptance: fused ranks == composed ranks to float32 tolerance on a
+    """Acceptance: device ranks == composed ranks to float32 tolerance on a
     mixed-graph queue with attained service, same priority ordering."""
     r_comp = _filled(kb, "composed").priorities(10.0)
-    r_fus = _filled(kb, "fused", walker="threefry").priorities(10.0)
+    r_fus = _filled(kb, "fused_delta", walker="threefry").priorities(10.0)
     ids_c, vc = _vals(r_comp)
     ids_f, vf = _vals(r_fus)
     assert ids_c == ids_f
@@ -55,12 +56,15 @@ def test_fused_threefry_matches_composed_mixed_graphs(kb):
 
 def test_fused_threefry_matches_composed_with_overrides(kb):
     """Refinement overrides flow through the QueueState override tables
-    identically to the composed per-tick table rebuild."""
+    identically to the composed per-tick table rebuild.  The full-walk
+    fallback is off, so the device path re-walks only the four finished
+    apps, as the composed path re-estimates only their views."""
     out = {}
-    for mode, walker in (("composed", "pallas"), ("fused", "threefry")):
+    for mode, walker in (("composed", "pallas"), ("fused_delta", "threefry")):
         s = HermesScheduler(kb, t_in=T_IN, t_out=T_OUT, mc_walkers=32,
                             seed=7, refine=True,
-                            refresh=RefreshConfig(mode=mode, walker=walker))
+                            refresh=RefreshConfig(mode=mode, walker=walker,
+                                                  delta_full_threshold=1.0))
         for i in range(8):
             s.on_arrival(f"b{i}", "CG", now=float(i))
             s.on_progress(f"b{i}", 0.1 * i)
@@ -71,45 +75,48 @@ def test_fused_threefry_matches_composed_with_overrides(kb):
                              9.0, "generate")
         out[mode] = s.priorities(10.0)
     _, vc = _vals(out["composed"])
-    _, vf = _vals(out["fused"])
+    _, vf = _vals(out["fused_delta"])
     np.testing.assert_allclose(vc, vf, rtol=1e-5, atol=1e-5)
 
 
 def test_fused_subset_uses_cached_ranks(kb):
-    """A subset priorities() call with no stale views returns the cached
-    device ranks from the last full refresh."""
-    s = _filled(kb, "fused")
+    """A subset priorities() call with no dirty slot walks nothing and
+    returns the ranks of the last full refresh."""
+    s = _filled(kb, "fused_delta")
     full = s.priorities(10.0)
     some = sorted(full)[:5]
     sub = s.priorities(10.0, app_ids=some)
     assert sorted(sub) == sorted(some)
     for i in some:
         assert sub[i] == pytest.approx(full[i])
+    assert all(a.refreshes == 1 for a in s.apps.values())
 
 
 def test_fused_subset_dispatch_matches_composed(kb):
-    """A GENUINE subset fused dispatch (stale views -> slots gather path)
+    """A GENUINE subset device dispatch (dirty slots -> slots gather path)
     must rank like the composed path refreshing the same stale subset
     (same fold_in chain via walker='threefry')."""
     out = {}
-    for mode, walker in (("composed", "pallas"), ("fused", "threefry")):
+    for mode, walker in (("composed", "pallas"), ("fused_delta", "threefry")):
         s = _filled(kb, mode, walker=walker)
         s.priorities(10.0)
         some = sorted(s._live)[:5]
         for i in some:
-            s.apps[i].view = None          # force re-estimation
+            s.apps[i].view = None          # composed: force re-estimation
+            if s._qstate is not None:
+                s._qstate.mark_dirty(i)    # device: force a re-walk
         out[mode] = s.priorities(10.0, app_ids=some)
+        assert all(s.apps[i].refreshes == 2 for i in some)
     ids_c, vc = _vals(out["composed"])
-    ids_f, vf = _vals(out["fused"])
+    ids_f, vf = _vals(out["fused_delta"])
     assert ids_c == ids_f
     np.testing.assert_allclose(vc, vf, rtol=1e-5, atol=1e-5)
 
 
 def test_fused_rank_only_reuse_between_ticks(kb):
-    """Progress invalidates the cached device rank but NOT the cached
-    histogram: the next priorities() re-ranks from the hist rows without
-    re-walking anything."""
-    s = _filled(kb, "fused")
+    """Progress moves the rank but NOT the persisted histogram: the next
+    priorities() re-ranks from the hist rows without re-walking anything."""
+    s = _filled(kb, "fused_delta")
     full = s.priorities(10.0)
     before = {a.app_id: a.refreshes for a in s.apps.values()}
     s.on_progress("a000", 1.0)
@@ -119,14 +126,23 @@ def test_fused_rank_only_reuse_between_ticks(kb):
 
 
 def test_fused_resample_redraws_and_never_ships_samples(kb):
-    s = _filled(kb, "fused", n_apps=8)
+    """Resampling is demand-driven on the device path: a tick re-walks the
+    slots whose PDGraph position changed (here every slot), with fresh MC
+    draws, and neither samples nor histogram rows reach the host."""
+    s = _filled(kb, "fused_delta", n_apps=8)
     s.refresh_tick(5.0)
     refreshes = {a.app_id: a.refreshes for a in s.apps.values()}
+    qs = s._qstate
+    for a in s.apps.values():
+        qs.mark_dirty(a.app_id)
     ranks1 = s.refresh_tick(6.0, resample=True)
     for a in s.apps.values():
         assert a.refreshes == refreshes[a.app_id] + 1
-        assert a.view.total_samples is None        # device-resident
-        assert a.view.hist[0].shape == (s.n_buckets,)
+        assert a.view is None                      # device-resident
+    assert qs.d_probs.shape == (qs.capacity, s.n_buckets)
+    assert not isinstance(qs.d_probs, np.ndarray)
+    for a in s.apps.values():
+        qs.mark_dirty(a.app_id)
     ranks2 = s.refresh_tick(7.0, resample=True)
     _, v1 = _vals(ranks1)
     _, v2 = _vals(ranks2)
@@ -138,7 +154,8 @@ def test_fused_pallas_orders_like_composed(kb):
     with shared seeds the two orderings must still agree strongly — a rank
     correlation collapse means a walker defect, not MC noise."""
     r_comp = _filled(kb, "composed", n_apps=32).priorities(10.0)
-    r_fus = _filled(kb, "fused", walker="pallas", n_apps=32).priorities(10.0)
+    r_fus = _filled(kb, "fused_delta", walker="pallas",
+                    n_apps=32).priorities(10.0)
     _, vc = _vals(r_comp)
     _, vf = _vals(r_fus)
     rc = np.argsort(np.argsort(vc))
@@ -150,7 +167,7 @@ def test_fused_pallas_orders_like_composed(kb):
 def test_queue_state_incremental_matches_rebuild(kb):
     """The incrementally-maintained QueueState (arrivals, progress, unit
     advance, overrides, retirement) must equal a from-scratch rebuild."""
-    s = _filled(kb, "fused", n_apps=12)
+    s = _filled(kb, "fused_delta", n_apps=12)
     s.priorities(5.0)                              # forces qstate creation
     s.on_unit_finish("a003", s.apps["a003"].current_unit,
                      {"in": 100, "out": 50, "par": 1, "dur": 1.0}, 6.0, None)
@@ -175,24 +192,25 @@ def test_queue_state_incremental_matches_rebuild(kb):
 
 
 def test_fused_ranks_stay_aligned_after_retirement(kb):
-    """Retiring an app swap-compacts QueueState slots, diverging slot order
-    from _live insertion order; the full-queue fused refresh must keep each
-    rank attached to ITS app (regression: ranks were zipped across orders)."""
+    """Retiring an app leaves holes in the slot arena, diverging slot order
+    from _live insertion order; the full-queue device refresh must keep
+    each rank attached to ITS app (regression: ranks were zipped across
+    orders)."""
     out = {}
-    for mode, walker in (("composed", "pallas"), ("fused", "threefry")):
+    for mode, walker in (("composed", "pallas"), ("fused_delta", "threefry")):
         s = _filled(kb, mode, walker=walker, n_apps=12)
         s.priorities(10.0)
-        s.on_app_complete("a001")          # swap-with-last compaction
+        s.on_app_complete("a001")          # holes in the arena
         s.on_app_complete("a004")
-        out[mode] = s.refresh_tick(12.0, resample=True)
+        out[mode] = s.refresh_tick(12.0)
     ids_c, vc = _vals(out["composed"])
-    ids_f, vf = _vals(out["fused"])
+    ids_f, vf = _vals(out["fused_delta"])
     assert ids_c == ids_f and "a001" not in ids_c
     np.testing.assert_allclose(vc, vf, rtol=1e-5, atol=1e-5)
 
 
 def test_fused_spill_counter_starts_clean(kb):
-    s = _filled(kb, "fused", n_apps=16)
+    s = _filled(kb, "fused_delta", n_apps=16)
     s.refresh_tick(5.0, resample=True)
     assert s.fused_spill == 0
 
@@ -201,6 +219,7 @@ def test_fused_no_phantom_spill_from_queue_padding(kb):
     """Padding rows (20 apps pad to 32) walk as garbage-but-valid apps;
     their walkers must start absorbed so they neither occupy compaction
     capacity nor surface as phantom spill."""
-    s = _filled(kb, "fused", n_apps=20, compact_after=4, compact_shrink=4)
+    s = _filled(kb, "fused_delta", n_apps=20, compact_after=4,
+                compact_shrink=4)
     s.refresh_tick(5.0, resample=True)
     assert s.fused_spill == 0

@@ -136,7 +136,7 @@ def test_arena_slots_retired_exactly_once_under_shedding(kb):
     insts = _crowd(kb)
     sim = ClusterSim(kb, SimConfig(
         seed=5, prewarm_mode="lru", n_llm_slots=8, mc_walkers=64,
-        policy="hermes_ddl", refresh=RefreshConfig(mode="fused"),
+        policy="hermes_ddl", refresh=RefreshConfig(mode="fused_delta"),
         admission=AdmissionConfig(pressure_watermark=1.0)))
     res = sim.run(list(insts))
     qs = sim.sched._qstate
@@ -154,7 +154,7 @@ def test_shed_is_idempotent_on_scheduler(kb):
     insts = _crowd(kb)
     sim = ClusterSim(kb, SimConfig(
         seed=5, prewarm_mode="lru", n_llm_slots=8, mc_walkers=64,
-        policy="hermes_ddl", refresh=RefreshConfig(mode="fused"),
+        policy="hermes_ddl", refresh=RefreshConfig(mode="fused_delta"),
         admission=AdmissionConfig(pressure_watermark=1.0)))
     res = sim.run(list(insts))
     qs = sim.sched._qstate

@@ -288,5 +288,5 @@ def test_posterior_config_validation():
 def test_posterior_requires_fused_delta_mode():
     with pytest.raises(ValueError, match="fused_delta"):
         HermesScheduler(_kb(), policy="gittins",
-                        refresh=RefreshConfig(mode="fused"),
+                        refresh=RefreshConfig(mode="composed"),
                         posterior=PosteriorConfig())

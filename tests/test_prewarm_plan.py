@@ -53,7 +53,7 @@ def _branch_kb(p_b=0.5, dur_a=30.0):
 def _sched(kb, **kw):
     base = dict(policy="gittins", t_in=T_IN, t_out=T_OUT, mc_walkers=512,
                 seed=3, prewarm=True,
-                refresh=RefreshConfig(mode="fused", walker="pallas"))
+                refresh=RefreshConfig(mode="fused_delta", walker="pallas"))
     base.update(kw)
     return HermesScheduler(kb, **base)
 

@@ -194,7 +194,8 @@ def test_mesh_event_path_subset_updates_full_tick_ranks(kb, n_shards):
 def test_mesh_requires_delta_mode(kb):
     with pytest.raises(ValueError, match="fused_delta"):
         HermesScheduler(kb, policy="gittins",
-                        refresh=RefreshConfig(mode="fused", mesh_shards=1))
+                        refresh=RefreshConfig(mode="composed",
+                                              mesh_shards=1))
 
 
 def test_mesh_shard_count_guards(kb):

@@ -108,12 +108,13 @@ def test_same_timestamp_events_coalesced(kb):
 
 
 def test_fused_refresh_mode_runs_sim(kb, workload):
-    """End-to-end simulation on the fused device-resident refresh pipeline:
+    """End-to-end simulation on the device-resident refresh pipeline:
     every app completes and the schedule quality matches the composed path
     (same policy, different-but-equivalent MC draws)."""
-    composed = _run(kb, list(workload)[:60], policy="gittins")
+    composed = _run(kb, list(workload)[:60], policy="gittins",
+                    refresh=RefreshConfig(mode="composed"))
     fused = _run(kb, list(workload)[:60], policy="gittins",
-                 refresh=RefreshConfig(mode="fused"))
+                 refresh=RefreshConfig(mode="fused_delta"))
     assert len(fused.acts) == 60
     assert fused.mean_act() <= 1.25 * composed.mean_act()
     assert composed.mean_act() <= 1.25 * fused.mean_act()

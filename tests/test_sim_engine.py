@@ -14,10 +14,9 @@ default tier only checks that the warning fires); the full heap/calendar
 equivalence suite runs on the slow tier (``-m slow``) until the heap loop
 is removed.
 
-Also here: the RefreshConfig deprecation-shim round-trips (legacy kwargs
-warn but resolve to the identical config; mixing old and new spellings is a
-TypeError) and the ``repro.core.refresh`` facade / legacy prewarm entry
-point deprecations.
+Also here: the RefreshConfig construction surface (its validation, both
+entry points' defaults, the retired per-field spellings refused) and
+``PrewarmPlan.merge``.
 """
 import warnings
 
@@ -29,7 +28,7 @@ from hypothesis import strategies as st
 from repro.apps.suite import T_IN, T_OUT, build_knowledge_base
 from repro.apps.workload import make_open_workload, make_workload
 from repro.core.prewarm import PrewarmPlan
-from repro.core.refresh_config import RefreshConfig, resolve_refresh_config
+from repro.core.refresh_config import RefreshConfig
 from repro.core.scheduler import HermesScheduler
 from repro.serving.events import (ArrayWaitQueue, CalendarEventQueue,
                                   HeapEventQueue, HeapWaitQueue,
@@ -324,12 +323,13 @@ def test_engines_bit_equivalent_with_posterior_on_drift_trace():
 # ------------------------------------------------- RefreshConfig round-trips
 
 def test_refresh_config_validation():
-    with pytest.raises(ValueError, match="mode"):
-        RefreshConfig(mode="warp")
+    for mode in ("warp", "fused", "looped"):
+        with pytest.raises(ValueError, match="unknown refresh mode"):
+            RefreshConfig(mode=mode)
     with pytest.raises(ValueError, match="walker"):
         RefreshConfig(walker="xorshift")
-    with pytest.raises(ValueError, match="fused_delta"):
-        RefreshConfig(mode="fused", mesh_shards=2)
+    with pytest.raises(ValueError, match="requires mode='fused_delta'"):
+        RefreshConfig(mode="composed", mesh_shards=2)
     with pytest.raises(ValueError, match="power of two"):
         RefreshConfig(mesh_shards=3)
     with pytest.raises(ValueError, match="delta_full_threshold"):
@@ -339,18 +339,19 @@ def test_refresh_config_validation():
 
 
 def test_legacy_kwargs_are_retired():
-    """The per-field refresh kwargs (deprecated in the previous release)
-    now raise a TypeError that names the offending kwargs and spells out
-    the RefreshConfig replacement — on the resolver and on both public
-    construction surfaces."""
-    with pytest.raises(TypeError, match="mode.*removed") as exc:
-        resolve_refresh_config(None, owner="X", mode="fused",
-                               walker="threefry",
-                               delta_full_threshold=0.25)
-    assert "RefreshConfig(" in str(exc.value)             # migration pointer
-    assert "walker='threefry'" in str(exc.value)
-    with pytest.raises(TypeError, match="removed"):
-        resolve_refresh_config(RefreshConfig(), owner="X", mode="fused")
+    """Every retired per-field refresh kwarg is an unexpected keyword on
+    both public construction surfaces: the field lives on RefreshConfig."""
+    kb = _kb()
+    for kw in (dict(batched=False), dict(mode="fused"),
+               dict(walker="threefry"), dict(mesh_shards=1),
+               dict(delta_full_threshold=0.25),
+               dict(queue_delay_correction=True)):
+        with pytest.raises(TypeError, match="unexpected keyword"):
+            HermesScheduler(kb, **kw)
+    for kw in (dict(refresh_mode="fused"), dict(walker="threefry"),
+               dict(mesh_shards=1), dict(queue_delay_correction=True)):
+        with pytest.raises(TypeError, match="unexpected keyword"):
+            SimConfig(**kw)
 
 
 def test_scheduler_accepts_refresh_config_and_keeps_bare_default():
@@ -361,10 +362,9 @@ def test_scheduler_accepts_refresh_config_and_keeps_bare_default():
                                                       walker="threefry"))
         assert (s.mode, s.walker) == ("fused_delta", "threefry")
         assert s.refresh_config.mode == "fused_delta"
-        # bare construction keeps the pre-RefreshConfig defaults
+        # a bare scheduler refreshes on the composed host path
         assert HermesScheduler(kb).mode == "composed"
-        assert HermesScheduler(kb, batched=False).mode == "looped"
-    with pytest.raises(TypeError, match="HermesScheduler.*removed"):
+    with pytest.raises(TypeError, match="unexpected keyword"):
         HermesScheduler(kb, mode="fused", walker="threefry")
 
 
@@ -374,38 +374,31 @@ def test_simconfig_accepts_refresh_config_and_rejects_legacy():
         cfg = SimConfig(refresh=RefreshConfig(mode="composed"))
         assert cfg.refresh.mode == "composed"
         assert SimConfig().refresh == RefreshConfig()     # sim default
-    with pytest.raises(TypeError, match="SimConfig.*removed"):
+    with pytest.raises(TypeError, match="unexpected keyword"):
         SimConfig(refresh_mode="fused", walker="threefry",
                   queue_delay_correction=True)
     with pytest.raises(ValueError, match="unknown sim engine"):
         SimConfig(engine="abacus")
 
 
-# ------------------------------------------------------------- deprecations
+# ------------------------------------------------------ PrewarmPlan.merge
 
-def test_refresh_facade_reexports_with_warning():
-    import repro.core.refresh as facade
-    from repro.core.arena import QueueState
-    with pytest.warns(DeprecationWarning, match="repro.core.arena"):
-        assert facade.QueueState is QueueState
-    from repro.core.refresh_pipeline import refresh_ranks_fused
-    with pytest.warns(DeprecationWarning, match="refresh_pipeline"):
-        assert facade.refresh_ranks_fused is refresh_ranks_fused
-    with pytest.raises(AttributeError):
-        facade.does_not_exist
-
-
-def test_prewarm_legacy_entry_points_warn_and_delegate():
-    from repro.core.prewarm import merge_plans
-    p1 = PrewarmPlan(app_ids=["a"], resource_keys=["kv:x"], kinds=["kv"],
-                     fire_at=np.asarray([5.0]), p_reach=np.asarray([0.9]))
-    p2 = PrewarmPlan(app_ids=["b"], resource_keys=["kv:y"], kinds=["kv"],
-                     fire_at=np.asarray([6.0]), p_reach=np.asarray([0.8]),
-                     units=["plan"])
-    with pytest.warns(DeprecationWarning, match="PrewarmPlan.merge"):
-        old = merge_plans(p1, p2, lambda a: True)
-    new = p1.merge(p2, lambda a: True)
-    assert old.app_ids == new.app_ids == ["a", "b"]
-    np.testing.assert_array_equal(old.fire_at, new.fire_at)
-    assert [old.unit_of(i) for i in range(2)] == \
-        [new.unit_of(i) for i in range(2)] == ["*", "plan"]
+def test_prewarm_plan_merge_dedups_and_prunes():
+    """``merge`` keeps one row per (app, class) with the newer plan's
+    trigger winning, drops apps that are no longer live, and carries each
+    row's unit ("*" where the plan names none)."""
+    p1 = PrewarmPlan(app_ids=["a", "c"], resource_keys=["kv:x", "kv:z"],
+                     kinds=["kv", "kv"], fire_at=np.asarray([5.0, 7.0]),
+                     p_reach=np.asarray([0.9, 0.7]))
+    p2 = PrewarmPlan(app_ids=["b", "a"], resource_keys=["kv:y", "kv:x"],
+                     kinds=["kv", "kv"], fire_at=np.asarray([6.0, 4.0]),
+                     p_reach=np.asarray([0.8, 0.95]),
+                     units=["plan", "gen"])
+    m = p1.merge(p2, lambda a: a != "c")
+    assert m.app_ids == ["a", "b"]
+    assert m.resource_keys == ["kv:x", "kv:y"]
+    np.testing.assert_array_equal(m.fire_at, [4.0, 6.0])   # newer wins
+    np.testing.assert_array_equal(m.p_reach,
+                                  np.asarray([0.95, 0.8], np.float32))
+    assert [m.unit_of(i) for i in range(2)] == ["gen", "plan"]
+    assert p1.merge(p2, lambda a: True).unit_of(1) == "*"       # c

@@ -9,7 +9,7 @@ Pins the PR's three contracts:
   (the acceptance claim behind the fused_delta benchmark arm), and the
   dirty-set semantics walk exactly what changed;
 * the composite policies' on-device triage (`hermes_ddl`/`lstf` in
-  ``refresh_mode="fused"``) matches the host-quantile path on float32 with
+  ``mode="fused_delta"``) matches the host-quantile path on float32 with
   no sample arrays ever reaching the host.
 """
 import numpy as np
@@ -23,8 +23,7 @@ from repro.core.pdgraph import (ARRIVAL_NEVER, BackendSpec, PDGraph,
                                 UnitNode, pack_graphs)
 from repro.core.prewarm import prewarm_trigger_time
 from repro.core.arena import QueueState
-from repro.core.refresh_pipeline import (refresh_ranks_delta,
-                                         refresh_ranks_fused)
+from repro.core.refresh_pipeline import refresh_ranks_delta
 from repro.core.refresh_config import RefreshConfig
 from repro.core.scheduler import HermesScheduler
 
@@ -132,31 +131,45 @@ def test_retired_slot_is_reused_before_growth(packed):
 # --------------------------------------------------- delta-tick bit identity
 def test_delta_bit_identical_to_full_rewalk_of_dirty_set(kb):
     """Acceptance: delta-refreshed ranks for the dirty apps equal a full
-    subset re-walk of the same slots to the BIT — gather → walk → scatter →
-    rank-in-place must not perturb a single float."""
+    re-walk of the whole arena at the same slots to the BIT — gather → walk
+    → scatter → rank-in-place must not perturb a single float."""
     for walker in ("threefry", "pallas"):
-        s = _filled(kb, "fused_delta", walker=walker)
-        s.priorities(10.0)                  # prime: all slots walked once
-        qs, packed = s._qstate, s._packed[1]
-        dirty = qs.occupied()[::3]          # any subset
-        kw = dict(n_walkers=MC, walker=walker)
-        full = refresh_ranks_fused(packed, qs, s._base_key, s._seed,
-                                   slots=dirty, **kw)
-        tick = refresh_ranks_delta(packed, qs, s._base_key, s._seed,
-                                   walked=dirty, **kw)
-        np.testing.assert_array_equal(tick.ranks[dirty], full.ranks,
-                                      err_msg=walker)
+        subset, whole = (_filled(kb, "fused_delta", walker=walker)
+                         for _ in range(2))
+        ticks = []
+        for s in (subset, whole):
+            s.priorities(10.0)              # prime: all slots walked once
+            qs = s._qstate
+            dirty = qs.occupied()[::3]      # any subset
+            walked = dirty if s is subset else qs.occupied()
+            ticks.append(refresh_ranks_delta(
+                s._packed[1], qs, s._base_key, s._seed, walked=walked,
+                n_walkers=MC, walker=walker))
+        np.testing.assert_array_equal(ticks[0].ranks[dirty],
+                                      ticks[1].ranks[dirty], err_msg=walker)
 
 
-def test_delta_scheduler_matches_fused_first_tick(kb):
-    """First tick (everything dirty -> full fallback) must rank exactly
-    like plain fused mode: same streams, same math, bitwise."""
-    rd = _filled(kb, "fused_delta").priorities(10.0)
-    rf = _filled(kb, "fused").priorities(10.0)
+@pytest.mark.parametrize("policy", ["gittins", "hermes_ddl", "lstf"])
+def test_delta_subset_tick_matches_first_full_tick(kb, policy):
+    """Admitting a queue in two parts (the second tick walks the 8 new
+    slots as a dirty subset) ranks exactly like admitting it at once (the
+    first tick walks everything): same streams, same math, bitwise — for
+    the Gittins rank and for the composite policies' device triage."""
+    rd = _filled(kb, "fused_delta", policy=policy).priorities(10.0)
+    s = _filled(kb, "fused_delta", policy=policy, n_apps=16)
+    s.priorities(10.0)
+    names = sorted(kb)
+    for i in range(16, 24):
+        aid = f"a{i:03d}"
+        s.on_arrival(aid, names[i % len(names)], now=0.25 * i,
+                     tenant=f"t{i % 4}", deadline=200.0 + 3.0 * i)
+        s.on_progress(aid, 0.05 * i)
+    rs = s.priorities(10.0)
+    assert sum(a.refreshes for a in s.apps.values()) == 24   # no re-walk
     ids_d, vd = _vals(rd)
-    ids_f, vf = _vals(rf)
-    assert ids_d == ids_f
-    np.testing.assert_array_equal(vd, vf)
+    ids_s, vs = _vals(rs)
+    assert ids_d == ids_s
+    np.testing.assert_array_equal(vd, vs)
 
 
 # ------------------------------------------------------- dirty-set semantics
@@ -213,15 +226,15 @@ def test_delta_survives_retirement_churn(kb):
     assert np.isfinite(list(r.values())).all()
 
 
-# ---------------------------------------------------------- fused triage
+# --------------------------------------------------------- device triage
 @pytest.mark.parametrize("policy", ["hermes_ddl", "lstf"])
 def test_composite_policy_fused_matches_host_path(kb, policy):
-    """hermes_ddl / lstf with refresh_mode='fused': triage quantiles come
+    """hermes_ddl / lstf with mode='fused_delta': triage quantiles come
     from the device dispatch (no sample arrays on host) and the ranks match
     the composed host-quantile path to float32 tolerance."""
     r_host = _filled(kb, "composed", policy=policy).priorities(10.0)
-    s = _filled(kb, "fused", walker="threefry", policy=policy)
-    assert s._fused_active()
+    s = _filled(kb, "fused_delta", walker="threefry", policy=policy)
+    assert s._delta_active()
     r_fused = s.priorities(10.0)
     ids_h, vh = _vals(r_host)
     ids_f, vf = _vals(r_fused)
@@ -229,37 +242,31 @@ def test_composite_policy_fused_matches_host_path(kb, policy):
     np.testing.assert_allclose(vh, vf, rtol=1e-5, atol=1e-3)
     assert np.array_equal(np.argsort(vh, kind="stable"),
                           np.argsort(vf, kind="stable"))
+    qs = s._qstate
+    occ = qs.occupied()
     for a in s.apps.values():       # no per-app host quantile pulls possible
-        assert a.view.total_samples is None
-        assert a.view.demand_sup is not None
-
-
-def test_composite_policy_fused_delta_runs_and_matches_fused(kb):
-    for policy in ("hermes_ddl", "lstf"):
-        rf = _filled(kb, "fused", policy=policy).priorities(10.0)
-        rd = _filled(kb, "fused_delta", policy=policy).priorities(10.0)
-        _, vf = _vals(rf)
-        _, vd = _vals(rd)
-        np.testing.assert_array_equal(vf, vd)
+        assert a.view is None
+    for col in (qs.sup, qs.opt, qs.mean):   # the device triage's mirrors
+        assert np.isfinite(col[occ]).all()
 
 
 def test_retuned_quantiles_fall_back_to_host_path(kb):
     """A policy instance re-tuned away from the device quantiles loses
-    fused eligibility instead of silently ranking on the wrong quantile."""
-    s = _filled(kb, "fused", policy="lstf")
+    device eligibility instead of silently ranking on the wrong quantile."""
+    s = _filled(kb, "fused_delta", policy="lstf")
     s.policy.sup_q = 0.95
-    assert not s._fused_active()
+    assert not s._delta_active()
     r = s.priorities(10.0)                  # composed fallback still ranks
     assert len(r) == 24
     assert any(a.view.total_samples is not None for a in s.apps.values())
 
 
 def test_retune_mid_run_reestimates_stale_fused_views(kb):
-    """Re-tuning AFTER fused views exist must re-estimate them on the host
-    path (device scalars are pinned to the stock quantiles and carry no
-    samples) — including with a mixed queue from a post-retune arrival."""
-    s = _filled(kb, "fused", policy="lstf")
-    s.priorities(10.0)                      # mint fused (sample-less) views
+    """Re-tuning AFTER device ticks must estimate every app on the host
+    path (the device path minted no views, and its scalars are pinned to
+    the stock quantiles) — including a post-retune arrival."""
+    s = _filled(kb, "fused_delta", policy="lstf")
+    s.priorities(10.0)                      # device tick: no views minted
     s.policy.sup_q = 0.95
     s.on_arrival("late", sorted(s.kb)[0], now=11.0, deadline=300.0)
     r = s.priorities(11.0)                  # mixed views must not crash
@@ -362,7 +369,7 @@ def test_queue_stretch_delays_prewarm_trigger():
         s = HermesScheduler(_chain_kb(dur_a=30.0), policy="gittins",
                             t_in=T_IN, t_out=T_OUT, mc_walkers=256, seed=3,
                             refresh=RefreshConfig(
-                                mode="fused", walker="pallas",
+                                mode="fused_delta", walker="pallas",
                                 queue_delay_correction=corrected),
                             prewarm=True)
         s.on_arrival("x", "T", now=0.0)
@@ -381,8 +388,8 @@ def test_queue_stretch_delays_prewarm_trigger():
 
 def test_store_arrival_rows_feed_the_plan(kb):
     """The batched plan is built from the store's persisted trigger rows
-    (plan_from_store), not a side-channel: rows for walked slots are fresh
-    and finite exactly where a plan entry exists."""
+    (PrewarmPlan.from_store), not a side-channel: rows for walked slots are
+    fresh and finite exactly where a plan entry exists."""
     s = HermesScheduler(_chain_kb(), policy="gittins", t_in=T_IN,
                         t_out=T_OUT, mc_walkers=256, seed=3,
                         refresh=RefreshConfig(mode="fused_delta",
